@@ -832,14 +832,6 @@ def crash_soak(smoke: bool = False, seed: int = 0, kills: int = 3,
                for _ in range(n_requests)]
     sp = SamplingParams(max_tokens=max_tokens, ignore_eos=True)
 
-    # fault-free greedy baseline (sync engine): the parity ground truth for
-    # both phases
-    base = _build_engine()
-    breqs = [base.submit(p, sp) for p in prompts]
-    for _ in base.stream():
-        pass
-    expected = [list(r.output_tokens) for r in breqs]
-
     plan = FaultPlan.crash(seed=seed, kills=kills, corruptions=1)
     if journal_dir is not None:          # CI: in-workspace, uploadable
         os.makedirs(journal_dir, exist_ok=True)
@@ -1005,6 +997,16 @@ def crash_soak(smoke: bool = False, seed: int = 0, kills: int = 3,
         if proc is not None and proc.poll() is None:
             proc.kill()
             proc.wait(timeout=60)
+
+    # fault-free greedy baseline (sync engine): the parity ground truth for
+    # both phases.  Computed only now, once every child has exited: the
+    # children need the device, and a parent that touched JAX would hold it
+    base = _build_engine()
+    breqs = [base.submit(p, sp) for p in prompts]
+    for _ in base.stream():
+        pass
+    expected = [list(r.output_tokens) for r in breqs]
+    del base, breqs
 
     # hard gates: nothing lost, nothing duplicated, greedy parity end-to-end
     if kills_delivered < kills:

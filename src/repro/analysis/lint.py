@@ -32,7 +32,12 @@ Rule catalog (ids are what suppressions and the baseline reference):
   copy of the pool every step.
 * ``pallas-align`` — a literal BlockSpec block dimension misaligned with
   the TPU tile: last dim must be 1 or a multiple of 128 (lane), second-to-
-  last 1 or a multiple of 8 (fp32 sublane).
+  last a multiple of 8 (fp32 sublane) — or equal to the array's dim.  A
+  literal 1 there is fine only where the array's dim is 1, which the lint
+  reads off the index map: a lambda that walks that axis (returns anything
+  but the literal 0 there) means the array's dim exceeds 1 and is flagged.
+  The lint cannot see array shapes; the compile tests
+  (tests/test_chip_compile.py) are the real guard.
 * ``pallas-grid-div`` — a grid extent computed with floor division ``//``
   instead of ``pl.cdiv``: silently drops the ragged tail unless the
   divisor provably divides (suppress with a justification where it does).
@@ -174,6 +179,21 @@ def _collect_module(path: pathlib.Path, rel: str) -> Optional[ModuleInfo]:
                     mod.functions.append(
                         FuncInfo(mod, sub, sub.name, node.name))
     return mod
+
+
+def _walks_axis(spec: ast.Call, pos: int) -> bool:
+    """Whether a BlockSpec's lambda index map returns anything but the
+    literal 0 at block position ``pos`` (the kernel then steps through that
+    axis, so the array's dim there exceeds the block's).  Named index maps
+    are not resolved and count as not walking."""
+    fn = spec.args[1] if len(spec.args) > 1 else None
+    if not (isinstance(fn, ast.Lambda) and isinstance(fn.body, ast.Tuple)):
+        return False
+    elts = fn.body.elts
+    if len(elts) < abs(pos):
+        return False
+    e = elts[pos]
+    return not (isinstance(e, ast.Constant) and e.value == 0)
 
 
 class Linter:
@@ -594,6 +614,14 @@ class Linter:
                             "pallas-align", mod, spec, fn.qualname,
                             f"literal {label} block dim {d.value} is "
                             f"neither 1 nor a multiple of {want}")
+                    elif pos == -2 and isinstance(d, ast.Constant) and \
+                            d.value == 1 and _walks_axis(spec, pos):
+                        self._emit(
+                            "pallas-align", mod, spec, fn.qualname,
+                            "second-to-last (sublane) block dim 1 over an "
+                            "axis the index map walks: the array's dim "
+                            "exceeds 1, so the block is neither a multiple "
+                            "of 8 nor the whole dim")
 
             # grid extents built with // drop the ragged tail
             for g in grids:

@@ -68,7 +68,6 @@ def make_compressed_allreduce(mesh, axis_name: str = "pod"):
     grads/err are pytrees replicated along `axis_name` (each pod computed its
     own data-parallel gradient); returns (mean grads, new err).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def reduce_tree(grads, err):
@@ -85,5 +84,5 @@ def make_compressed_allreduce(mesh, axis_name: str = "pod"):
 
     # everything replicated except the implicit axis_name dimension
     spec = P()
-    return shard_map(reduce_tree, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec), check_rep=False)
+    return jax.shard_map(reduce_tree, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False)

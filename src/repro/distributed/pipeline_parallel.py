@@ -23,7 +23,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -76,9 +75,9 @@ def gpipe_forward(stage_fn: Callable, mesh: Mesh, axis_name: str,
         return outs
 
     params_spec = P(axis_name)
-    return shard_map(run, mesh=mesh,
-                     in_specs=(params_spec, P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(run, mesh=mesh,
+                         in_specs=(params_spec, P()),
+                         out_specs=P(), check_vma=False)
 
 
 def pipeline_stage_from_stack(stack, reps_per_stage: int):

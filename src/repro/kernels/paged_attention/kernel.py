@@ -88,8 +88,8 @@ def _kernel(idx_ref, bt_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
         def _finish():
             # fused scatter: splice the new K/V row into the current block
             # and write that one block back (pool outputs alias the inputs)
-            kn = kn_ref[0, 0]                          # [Dh], model dtype
-            vn = vn_ref[0, 0]
+            kn = kn_ref[0, 0, 0]                       # [Dh], model dtype
+            vn = vn_ref[0, 0, 0]
             off = idx % bs
             row = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) == off
             ko_ref[0, 0] = jnp.where(row, kn[None].astype(ko_ref.dtype),
@@ -116,8 +116,11 @@ def paged_attention_decode_kernel(
         k_pool: jax.Array, v_pool: jax.Array,
         block_tables: jax.Array, idx: jax.Array,
         scale: float, softcap: float = 0.0, interpret: bool = False):
-    """q [B, Hkv, g, Dh]; k_new/v_new [B, Hkv, Dh]; pools [N, Hkv, bs, Dh];
-    block_tables int32 [B, L]; idx int32 [B] (per-row write position).
+    """q [B, Hkv, g, Dh]; k_new/v_new [B, Hkv, 1, Dh]; pools
+    [N, Hkv, bs, Dh]; block_tables int32 [B, L]; idx int32 [B] (per-row
+    write position).  The new K/V carry a unit axis so their block's last
+    two dims ``(1, Dh)`` equal the array's: Mosaic refuses a block whose
+    second-to-last dim is 1 out of a larger array dim (here Hkv).
 
     Returns (out [B, Hkv, g, Dh] in pool dtype, k_pool', v_pool') with the
     new K/V scattered into each row's current block in place."""
@@ -138,8 +141,8 @@ def paged_attention_decode_kernel(
         grid=(bq, hkv, n_log),
         in_specs=[
             pl.BlockSpec((1, 1, g, dh), lambda b, h, i, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, dh), lambda b, h, i, *_: (b, h, 0)),
-            pl.BlockSpec((1, 1, dh), lambda b, h, i, *_: (b, h, 0)),
+            pl.BlockSpec((1, 1, 1, dh), lambda b, h, i, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, dh), lambda b, h, i, *_: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, dh), kv_map),
             pl.BlockSpec((1, 1, bs, dh), kv_map),
         ],

@@ -48,7 +48,8 @@ def paged_attention_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     qg = q.reshape(b, hkv, g, dh)
     scale = float(1.0 / (dh ** 0.5))
     out, k_pool, v_pool = paged_attention_decode_kernel(
-        qg, k_new, v_new, k_pool, v_pool, block_tables, idx,
+        qg, k_new[:, :, None], v_new[:, :, None], k_pool, v_pool,
+        block_tables, idx,
         scale=scale, softcap=float(softcap), interpret=itp)
     return out.reshape(b, hq, dh), k_pool, v_pool
 

@@ -19,8 +19,9 @@ def paged_attention_decode_ref(q: jax.Array, k_new: jax.Array,
                                v_pool: jax.Array, block_tables: jax.Array,
                                idx: jax.Array, scale: float,
                                softcap: float = 0.0):
-    """Same contract as kernel.paged_attention_decode_kernel:
-    q [B, Hkv, g, Dh]; k_new/v_new [B, Hkv, Dh]; pools [N, Hkv, bs, Dh];
+    """Same contract as kernel.paged_attention_decode_kernel, minus the
+    new K/V's unit axis: q [B, Hkv, g, Dh]; k_new/v_new [B, Hkv, Dh];
+    pools [N, Hkv, bs, Dh];
     block_tables [B, L]; idx [B] -> (out [B, Hkv, g, Dh], k_pool', v_pool')."""
     b, hkv, g, dh = q.shape
     bs = k_pool.shape[2]

@@ -89,7 +89,7 @@ def _kernel(s_i_ref, t_i_ref, s_j_ref, t_j_ref, o_ref,
         kl = (u_ref[...] / zt
               - (mt_ref[...] + jnp.log(zt))
               + (ms_ref[...] + jnp.log(zs)))
-        o_ref[0] = kl[:, 0].astype(o_ref.dtype)
+        o_ref[0, 0] = kl[:, 0].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bl", "bj", "temp", "interpret"))
@@ -109,8 +109,10 @@ def relation_kl_rows_kernel(s: jax.Array, t: jax.Array, temp: float = 1.0,
             pl.BlockSpec((1, bj, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bj, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bl), lambda b, i, j: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((bh, l), jnp.float32),
+        # rows come out as [BH, 1, L]: a (1, bl) block over [BH, L] would
+        # put a 1 in the sublane dim out of BH, which the TPU tiling refuses
+        out_specs=pl.BlockSpec((1, 1, bl), lambda b, i, j: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, l), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bl, 1), jnp.float32) for _ in range(5)],
         interpret=interpret,
-    )(s, t, s, t)
+    )(s, t, s, t)[:, 0]

@@ -36,8 +36,8 @@ def _kernel(x_ref, a_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref, h_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0].astype(jnp.float32)        # [Q, P]
-    a = a_ref[0].astype(jnp.float32)        # [Q]
-    dt = dt_ref[0].astype(jnp.float32)      # [Q]
+    a = a_ref[0, 0].astype(jnp.float32)     # [Q]
+    dt = dt_ref[0, 0].astype(jnp.float32)   # [Q]
     B = b_ref[0].astype(jnp.float32)        # [Q, N]
     C = c_ref[0].astype(jnp.float32)        # [Q, N]
     q = x.shape[0]
@@ -82,13 +82,15 @@ def ssd_scan_kernel(x: jax.Array, a: jax.Array, dt: jax.Array, B: jax.Array,
         q -= 1
     # the loop above shrank q until it divides s exactly, so // drops nothing
     grid = (bh, s // q)  # lint: allow(pallas-grid-div)
+    # a/dt ride as [BH, 1, S]: a (1, q) block over [BH, S] would put a 1 in
+    # the sublane dim out of BH, which the TPU tiling refuses
     return pl.pallas_call(
         functools.partial(_kernel, n_chunks=grid[1]),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, q, p), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, q), lambda b, c: (b, c)),
-            pl.BlockSpec((1, q), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, q), lambda b, c: (b, 0, c)),
+            pl.BlockSpec((1, 1, q), lambda b, c: (b, 0, c)),
             pl.BlockSpec((1, q, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, q, n), lambda b, c: (b, c, 0)),
         ],
@@ -102,4 +104,4 @@ def ssd_scan_kernel(x: jax.Array, a: jax.Array, dt: jax.Array, B: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, a, dt, B, C)
+    )(x, a[:, None], dt[:, None], B, C)

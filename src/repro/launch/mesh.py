@@ -12,12 +12,20 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, under which the sharding
+    # rules' with_sharding_constraint and the embedding gather raise; the
+    # plans in distributed/sharding.py are written for Auto axes
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(dp: int, tp: int, pods: int = 1):
@@ -27,8 +35,8 @@ def make_mesh(dp: int, tp: int, pods: int = 1):
     if want > n:
         raise ValueError(f"mesh {pods}x{dp}x{tp}={want} exceeds {n} devices")
     if pods > 1:
-        return jax.make_mesh((pods, dp, tp), ("pod", "data", "model"))
-    return jax.make_mesh((dp, tp), ("data", "model"))
+        return _auto_mesh((pods, dp, tp), ("pod", "data", "model"))
+    return _auto_mesh((dp, tp), ("data", "model"))
 
 
 def largest_feasible_mesh(tp: int = 16, pods: int = 1):

@@ -61,15 +61,18 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import signal
 import sys
 import time
 from collections import Counter
+from typing import List
 
 import jax
 import numpy as np
 
 from repro.core import quant as Q
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.base import get_config
 from repro.serving.api import SamplingParams
@@ -86,6 +89,8 @@ from repro.serving.tracing import Tracer
 # restart budget was exhausted (EngineCrash) — restartable with backoff.
 EXIT_CLEAN_DRAIN = 0
 EXIT_RESTART_EXHAUSTED = 17
+# load-generator runs: some request ended in error or got no events
+EXIT_REQUESTS_FAILED = 1
 
 
 def build_engine(args) -> Engine:
@@ -249,7 +254,19 @@ def export_trace(eng: Engine, args) -> None:
           "https://ui.perfetto.dev or chrome://tracing")
 
 
-async def run_load(eng: Engine, args) -> None:
+@dataclasses.dataclass
+class LoadResult:
+    """What ``run_load`` saw: each request's streamed events in arrival
+    order, the wall seconds from the first arrival to the last finish, the
+    tokens delivered, and the engine at the end (a supervisor restart swaps
+    it)."""
+    events: List[List[dict]]
+    seconds: float
+    tokens: int
+    engine: Engine
+
+
+async def run_load(eng: Engine, args) -> LoadResult:
     """Many-client load generator through the TCP front-end: one connection
     per request, arrivals on a schedule.  ``--arrival-rate 0`` is the closed
     loop (every arrival at t=0, drain); ``> 0`` draws Poisson inter-arrival
@@ -312,6 +329,7 @@ async def run_load(eng: Engine, args) -> None:
               f"({met / max(dt, 1e-9):.2f} good req/s)")
     print_stats(eng)
     export_trace(eng, args)
+    return LoadResult(results, dt, n_tok, eng)
 
 
 def _make_supervisor(eng: Engine, args):
@@ -385,7 +403,7 @@ async def run_server(eng: Engine, args) -> None:
     export_trace(aeng.engine, args)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -485,8 +503,12 @@ def main(argv=None):
     ap.add_argument("--tail-len", type=int, default=8,
                     help="random per-request tail tokens after a shared "
                          "prefix")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
     eng = build_engine(args)
     if args.serve:
         try:
@@ -498,7 +520,14 @@ def main(argv=None):
             sys.exit(EXIT_RESTART_EXHAUSTED)
         sys.exit(EXIT_CLEAN_DRAIN)
     else:
-        asyncio.run(run_load(eng, args))
+        res = asyncio.run(run_load(eng, args))
+        failed = sum(1 for evs in res.events
+                     if not evs or evs[-1].get("finish_reason") == "error")
+        if failed:
+            # a supervised run retries and quarantines, and still drains:
+            # requests that ended in error must fail the run
+            print(f"[load] {failed} request(s) failed", file=sys.stderr)
+            sys.exit(EXIT_REQUESTS_FAILED)
 
 
 if __name__ == "__main__":

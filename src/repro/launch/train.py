@@ -6,11 +6,14 @@ SIGTERM emergency save, straggler watchdog, optional cross-pod gradient
 compression).
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
-        --dp 2 --tp 1 --steps 200 --task sst2-syn --ckpt-dir /tmp/run1
+        --dp 2 --tp 1 --steps 200 --task sst2-syn --ckpt-dir runs/run1
 
-On this CPU container you'd pass small dp/tp; on a pod, --dp 16 --tp 16.
-The same entry point is what a 1000-node deployment supervises per-host
-(jax.distributed.initialize is a no-op single-host).
+--dp x --tp must not exceed the devices JAX sees: one chip takes --dp 1
+--tp 1, a four-chip host --dp 4, a pod --dp 16 --tp 16.  The same entry
+point is what a 1000-node deployment supervises per-host
+(jax.distributed.initialize is a no-op single-host).  Without --ckpt-dir
+nothing is saved or resumed.  ``main()`` returns each step's metrics
+(``loss``, ``grad_norm``, ``lr``, ...) as floats.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ import argparse
 import signal
 import sys
 import time
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +35,7 @@ from repro.data.synth import get_task
 from repro.distributed import sharding as shlib
 from repro.distributed.elastic import StepWatchdog
 from repro.distributed.sharding import ShardingPlan, default_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.models.base import get_config
@@ -39,7 +44,7 @@ from repro.training.schedule import warmup_cosine
 from repro.training.trainer import TrainState, init_train_state, make_train_step
 
 
-def main(argv=None):
+def main(argv=None) -> List[Dict[str, float]]:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--dp", type=int, default=1)
@@ -50,13 +55,15 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-4)
     ap.add_argument("--task", default="corpus")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint and resume directory (default: none)")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--reduced", action="store_true",
                     help="train the smoke-scale config (CPU-friendly)")
     ap.add_argument("--quant", default="qat", choices=["fp", "qat"])
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -89,61 +96,70 @@ def main(argv=None):
                         host_id=jax.process_index(),
                         num_hosts=jax.process_count())
     loader.start_prefetch()
-    mgr = CheckpointManager(args.ckpt_dir, save_every=args.ckpt_every)
+    mgr = (CheckpointManager(args.ckpt_dir, save_every=args.ckpt_every)
+           if args.ckpt_dir else None)
     watchdog = StepWatchdog()
+    history = []
 
     # ---- init or resume ------------------------------------------------------
     shlib.set_plan(plan)
-    with mesh:
-        if latest_step(args.ckpt_dir) is not None:
-            tmpl = jax.eval_shape(lambda: init_train_state(
-                model.init(jax.random.PRNGKey(0)), opt))
-            state, extra, start = load_checkpoint(
-                args.ckpt_dir, tmpl, shardings=state_sh)
-            loader.load_state_dict(extra.get("loader", {"step": 0}))
-            print(f"[resume] from step {start}")
-        else:
-            init_fn = jax.jit(
-                lambda k: init_train_state(model.init(k), opt),
-                out_shardings=state_sh)
-            state = init_fn(jax.random.PRNGKey(0))
-            start = 0
+    stop = {"now": False}
 
-        stop = {"now": False}
+    def on_term(sig, frm):
+        stop["now"] = True
+    prev_term = signal.signal(signal.SIGTERM, on_term)
+    try:
+        with mesh:
+            if mgr is not None and latest_step(args.ckpt_dir) is not None:
+                tmpl = jax.eval_shape(lambda: init_train_state(
+                    model.init(jax.random.PRNGKey(0)), opt))
+                state, extra, start = load_checkpoint(
+                    args.ckpt_dir, tmpl, shardings=state_sh)
+                loader.load_state_dict(extra.get("loader", {"step": 0}))
+                print(f"[resume] from step {start}")
+            else:
+                init_fn = jax.jit(
+                    lambda k: init_train_state(model.init(k), opt),
+                    out_shardings=state_sh)
+                state = init_fn(jax.random.PRNGKey(0))
+                start = 0
 
-        def on_term(sig, frm):
-            stop["now"] = True
-        signal.signal(signal.SIGTERM, on_term)
-
-        t_start = time.time()
-        for i in range(start, args.steps):
-            watchdog.start()
-            batch = {k: jnp.asarray(v) for k, v in loader.next().items()
-                     if k in ("tokens", "labels", "loss_mask")}
-            state, metrics = step_fn(state, batch)
-            flag = watchdog.stop()
-            if flag:
-                print(f"[straggler] step {flag.step}: {flag.duration:.3f}s "
-                      f"(median {flag.median:.3f}s)")
-            if i % args.log_every == 0:
-                m = {k: float(v) for k, v in metrics.items()}
-                print(f"step {i}  loss {m.get('loss', float('nan')):.4f}  "
-                      f"lr {m.get('lr', 0):.2e}  "
-                      f"({(time.time()-t_start):.1f}s)")
-            if mgr.should_save(i + 1):
-                mgr.save_async(i + 1, state,
-                               extra={"loader": loader.state_dict()})
-            if stop["now"]:
-                print("[sigterm] emergency checkpoint")
-                mgr.emergency_save(i + 1, state,
+            t_start = time.time()
+            for i in range(start, args.steps):
+                watchdog.start()
+                batch = {k: jnp.asarray(v) for k, v in loader.next().items()
+                         if k in ("tokens", "labels", "loss_mask")}
+                state, metrics = step_fn(state, batch)
+                history.append(metrics)
+                flag = watchdog.stop()
+                if flag:
+                    print(f"[straggler] step {flag.step}: "
+                          f"{flag.duration:.3f}s (median {flag.median:.3f}s)")
+                if i % args.log_every == 0:
+                    m = {k: float(v) for k, v in metrics.items()}
+                    print(f"step {i}  loss {m.get('loss', float('nan')):.4f}  "
+                          f"lr {m.get('lr', 0):.2e}  "
+                          f"({(time.time()-t_start):.1f}s)")
+                if mgr is not None and mgr.should_save(i + 1):
+                    mgr.save_async(i + 1, state,
                                    extra={"loader": loader.state_dict()})
-                sys.exit(0)
-        mgr.wait()
-        mgr.emergency_save(args.steps, state,
-                           extra={"loader": loader.state_dict()})
-    shlib.set_plan(None)
-    loader.stop_prefetch()
+                if stop["now"]:
+                    if mgr is not None:
+                        print("[sigterm] emergency checkpoint")
+                        mgr.emergency_save(
+                            i + 1, state,
+                            extra={"loader": loader.state_dict()})
+                    sys.exit(0)
+            if mgr is not None:
+                mgr.wait()
+                mgr.emergency_save(args.steps, state,
+                                   extra={"loader": loader.state_dict()})
+    finally:
+        signal.signal(signal.SIGTERM, prev_term)
+        shlib.set_plan(None)
+        loader.stop_prefetch()
     print("done")
+    return [{k: float(v) for k, v in m.items()} for m in history]
 
 
 if __name__ == "__main__":

@@ -1534,10 +1534,17 @@ def convert_to_packed(cfg: ModelConfig, qat_params) -> Tuple[ModelConfig, dict]:
     def walk(src, dst):
         if isinstance(dst, dict):
             if set(dst.keys()) >= {"w_packed", "delta"} and "w" in src:
-                k = src["w"].shape[0]
-                if k % 4 == 0:
-                    return convert_linear_params_fp_to_packed(src["w"])
-                return dst  # non-packable (K % 4 != 0) stays at init
+                w = src["w"]
+                if w.shape[-2] % 4 != 0:
+                    return dst  # non-packable (K % 4 != 0) stays at init
+                # scanned stacks hold [repeats, ..., K, N]: each layer packs
+                # on its own, with its own delta
+                conv = convert_linear_params_fp_to_packed
+                for _ in range(w.ndim - 2):
+                    conv = jax.vmap(conv)
+                out = conv(w)
+                return {**{k: walk(src.get(k), v) for k, v in dst.items()
+                           if k not in out}, **out}    # keeps the bias
             return {k: walk(src.get(k, None), v) if isinstance(src, dict)
                     else v for k, v in dst.items()}
         if src is not None and hasattr(src, "shape") and \

@@ -236,6 +236,28 @@ class TestLintRules:
         assert "pallas-align" in got      # last dim 100: not 1 / x128
         assert "pallas-grid-div" in got   # n // 4 in the grid
 
+    @pytest.mark.parametrize("block,index,flagged", [
+        # a 1 over the kv-head axis the map walks: Mosaic refuses it
+        ("(1, 1, dh)", "(b, h, 0)", True),
+        # the same row with a unit axis of its own: block == array dim
+        ("(1, 1, 1, dh)", "(b, h, 0, 0)", False),
+    ])
+    def test_pallas_align_sublane_one(self, tmp_path, block, index, flagged):
+        lint = _tree(tmp_path, {"kernels/k/kernel.py": f"""
+            import jax
+            from jax.experimental import pallas as pl
+
+            def k(x, dh, interpret=False):
+                return pl.pallas_call(
+                    _body,
+                    grid=(4, 2),
+                    in_specs=[pl.BlockSpec({block}, lambda b, h: {index})],
+                    out_specs=pl.BlockSpec({block}, lambda b, h: {index}),
+                    out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+                )(x)
+            """})
+        assert ("pallas-align" in _rules(lint)) == flagged
+
     def test_kernel_ref_parity(self, tmp_path):
         files = {"kernels/k/kernel.py": """
             def fused_op_kernel(x, w, bm=8, interpret=False):

@@ -25,11 +25,11 @@ def run_with_devices(body: str, n: int = 8) -> str:
 class TestCollectives:
     def test_compressed_psum_error_feedback(self):
         run_with_devices("""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         xs = jax.random.normal(jax.random.PRNGKey(0), (8, 1024)) * 3
         err0 = jnp.zeros((8, 1024))
 
@@ -37,8 +37,8 @@ class TestCollectives:
             y, ne = compressed_psum(x[0], "pod", e[0])
             return y[None], ne[None]
 
-        g = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                      out_specs=(P("pod"), P("pod")), check_rep=False)
+        g = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                          out_specs=(P("pod"), P("pod")), check_vma=False)
         y, err = g(xs, err0)
         exact = jnp.mean(xs, axis=0)
         # every shard sees the same mean, approx equal to exact

@@ -296,6 +296,28 @@ class TestPacked:
                                    np.asarray(logits_qat),
                                    rtol=1e-2, atol=1e-2)
 
+    def test_packed_conversion_of_trained_stacked_weights(self, qat_lm):
+        """Weights that are not the template's own init (a trained
+        student's) must be converted, per scanned layer, biases kept — at
+        any layer count, including the 24 of the full-width config."""
+        cfg, model, _ = qat_lm
+        params = model.init(jax.random.PRNGKey(7))
+        params = jax.tree_util.tree_map(lambda a: a + 0.01, params)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
+        logits_qat, _, _ = model.apply(params, toks)
+        pcfg, pparams = convert_to_packed(cfg, params)
+        logits_packed, _, _ = build_model(pcfg).apply(pparams, toks)
+        np.testing.assert_allclose(np.asarray(logits_packed),
+                                   np.asarray(logits_qat),
+                                   rtol=1e-2, atol=1e-2)
+        full = get_config("qwen1.5-0.5b").with_quant(Q.QAT)
+        shapes = jax.eval_shape(
+            lambda p: convert_to_packed(full, p)[1],
+            jax.eval_shape(build_model(full).init, jax.random.PRNGKey(0)))
+        wq = shapes["stack"]["pos0"]["attn"]["wq"]
+        assert wq["w_packed"].shape == (24, 1024 // 4, 1024)
+        assert wq["delta"].shape == (24,) and wq["b"].shape == (24, 1024)
+
     def test_packed_weight_bytes_8x_smaller_than_bf16(self):
         cfg = get_config("qwen1.5-0.5b").reduced().with_quant(Q.QAT)
         model = build_model(cfg)
