@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.serving.tracing import DECODE_KERNEL
+
 NEG_INF = -2.0e38
 
 
@@ -171,4 +173,5 @@ def paged_attention_decode_kernel(
         # keep their contents
         input_output_aliases={5: 1, 6: 2},
         interpret=interpret,
+        name=DECODE_KERNEL,       # the instruction name profile readers match
     )(idx, block_tables, q, k_new, v_new, k_pool, v_pool)

@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.serving.tracing import PREFILL_KERNEL
+
 NEG_INF = -2.0e38
 
 
@@ -195,4 +197,5 @@ def paged_prefill_chunk_kernel(
         # blocks keep their contents
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
+        name=PREFILL_KERNEL,       # the instruction name profile readers match
     )(start, lens, block_tables, q, k_chunk, v_chunk, k_pool, v_pool)

@@ -137,6 +137,16 @@ class EngineStats:
     done); ``prefill_positions_skipped`` counts positions covered by
     prefix-cache-shared blocks instead (zero prefill compute);
     ``prefill_chunks`` is how many per-slot chunks those positions took.
+    The step-work counters are summed once per committed step from its plan
+    at launch (``Engine``'s ``StepWork``): ``chunk_positions_run`` counts
+    the positions chunk steps computed (``max_batch`` x the chunk bucket,
+    what every linear layer runs) and ``chunk_positions_useful`` those that
+    carried a request (a prefilling row's chunk, a decoding row's token);
+    ``kv_blocks_walked`` counts the KV blocks the attention grid walked per
+    KV head per layer (``max_batch`` x table width, decode and chunk steps)
+    and ``kv_blocks_live`` those holding the active rows' positions.  Their
+    ratios are the fused step's useful share and the attention grid's live
+    share (0 blocks on the contiguous cache).
     ``ttft_ms`` holds time-to-first-token percentiles (mean / p50 / p95 /
     p99, wall-clock from submit to the first sampled token) once any request
     has produced one, else ``None``; ``queue_wait_ms`` the same percentiles
@@ -200,6 +210,10 @@ class EngineStats:
     prefill_positions: int = 0
     prefill_positions_skipped: int = 0
     prefill_chunks: int = 0
+    chunk_positions_run: int = 0
+    chunk_positions_useful: int = 0
+    kv_blocks_walked: int = 0
+    kv_blocks_live: int = 0
     tokens_generated: int = 0
     queue_depth: int = 0
     cancellations: int = 0

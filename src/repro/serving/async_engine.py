@@ -46,12 +46,22 @@ from __future__ import annotations
 import asyncio
 from typing import AsyncIterator, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.serving.api import (FinishReason, GenerationRequest,
                                SamplingParams, ServingError, StepOutput)
 from repro.serving.engine import Engine, InflightStep
 from repro.serving.supervisor import ServingSupervisor
+from repro.serving.tracing import SYNC
+
+
+def _synced_tokens(inflight: InflightStep) -> np.ndarray:
+    """The step's one device sync, run on an executor thread, written into
+    a ``jax.profiler`` capture as the ``serve.sync`` phase of its step."""
+    with jax.profiler.TraceAnnotation(SYNC, step=inflight.step,
+                                      kind=inflight.work.kind):
+        return np.asarray(inflight.tok)  # lint: allow(host-sync) budgeted
 
 
 class EngineOverloaded(RuntimeError):
@@ -276,9 +286,8 @@ class AsyncEngine:
                     # the only device sync per step, moved off-thread so the
                     # event loop keeps serving clients while the device runs
                     t_sync = eng.clock.now()
-                    sync = np.asarray  # lint: allow(host-sync) budgeted sync
                     tok_np = await loop.run_in_executor(
-                        None, sync, inflight.tok)
+                        None, _synced_tokens, inflight)
                     if eng.tracer is not None:
                         eng.tracer.sync_span(t_sync, eng.clock.now(),
                                              eng._steps_committed)
@@ -338,9 +347,8 @@ class AsyncEngine:
                 tok_np = None
                 if inflight.tok is not None:
                     t_sync = eng.clock.now()
-                    sync = np.asarray  # lint: allow(host-sync) budgeted sync
                     tok_np = await loop.run_in_executor(
-                        None, sync, inflight.tok)
+                        None, _synced_tokens, inflight)
                     if eng.tracer is not None:
                         eng.tracer.sync_span(t_sync, eng.clock.now(),
                                              eng._steps_committed)

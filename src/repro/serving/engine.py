@@ -97,7 +97,8 @@ from __future__ import annotations
 
 import dataclasses
 import zlib
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
+from typing import (Dict, Iterator, List, NamedTuple, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +114,7 @@ from repro.serving.prefix_cache import RadixPrefixCache
 from repro.serving.sampling import guard_nonfinite, sample_batch
 from repro.serving.scheduler import Scheduler, bucket_length
 from repro.serving.telemetry import Clock, Histogram, MetricsRegistry
+from repro.serving.tracing import COMMIT, LAUNCH, PLAN
 
 
 @dataclasses.dataclass
@@ -284,18 +286,50 @@ class StepPlan:
     spec: bool = False
 
 
+def step_kind(plan: StepPlan) -> str:
+    """Which program a plan launches: ``chunk`` when a slot prefills (or
+    rides along budget-stalled), ``decode`` otherwise, ``idle`` when no
+    slot is active and nothing is launched."""
+    if not plan.active:
+        return "idle"
+    return "chunk" if plan.chunks or plan.stalled else "decode"
+
+
+class StepWork(NamedTuple):
+    """What one launched step computes, counted on the host from its plan
+    (no device read).  ``positions_run``: positions every linear layer
+    computes (``max_batch x chunk bucket``, or ``max_batch`` for decode);
+    ``positions_useful``: those that carry a request (a chunk row's chunk,
+    a decoding row's one token; budget-stalled pad rows carry none).
+    ``kv_blocks_walked``: the block axis of the attention kernels' grid per
+    KV head per layer (``max_batch x table width``); ``kv_blocks_live``:
+    the blocks each active row's positions up to this step's last write
+    occupy.  Both block counts are 0 on the contiguous cache."""
+    kind: str
+    rows: int
+    positions_run: int
+    positions_useful: int
+    kv_blocks_walked: int
+    kv_blocks_live: int
+
+
 @dataclasses.dataclass
 class InflightStep:
     """A dispatched-but-uncommitted step: the plan it ran, the un-synced
     device array of sampled tokens (``None`` when no slot was active), and
     the wall-clock instant dispatch returned (for the step-gap metric).
-    ``write_blocks`` is the step's physical KV write-set, captured by the
-    sanitizer at launch (table state at dispatch time) so the
-    ``kv_checksums`` commit can digest exactly the blocks this step wrote."""
+    ``step`` is the id it commits under (a speculative launch runs one
+    ahead of the commits), ``work`` its :class:`StepWork`, added to the
+    stats only once it commits.  ``write_blocks`` is the step's physical
+    KV write-set, captured by the sanitizer at launch (table state at
+    dispatch time) so the ``kv_checksums`` commit can digest exactly the
+    blocks this step wrote."""
     plan: StepPlan
     tok: Optional[jax.Array]
     launched_at: float = 0.0
     write_blocks: Optional[List[int]] = None
+    step: int = 0
+    work: Optional[StepWork] = None
 
 
 class Engine:
@@ -416,6 +450,13 @@ class Engine:
         self._prefill_positions = 0
         self._prefill_skipped = 0
         self._prefill_chunks = 0
+        # step-work counters (StepWork, summed per committed step): useful
+        # against computed positions over chunk steps, live against walked
+        # KV blocks over steps of both kinds
+        self._chunk_positions_run = 0
+        self._chunk_positions_useful = 0
+        self._kv_blocks_walked = 0
+        self._kv_blocks_live = 0
         self._uid_counter = 0
         self._requests: Dict[int, GenerationRequest] = {}   # uid -> in flight
         self._submit_ts: Dict[int, float] = {}   # uid -> submit wall time
@@ -468,8 +509,9 @@ class Engine:
                                np.int32)
         self._keys = None                             # uint32 [slots, 2]
         # shape of the most recent fused step (active slots, per-slot
-        # positions, bucketed table width, chunk plan), set by step();
-        # telemetry for the serving benchmark's KV-traffic model
+        # positions, bucketed table width, chunk plan), set at launch before
+        # the tracer's launch_span fires: the serving benchmark (bench/)
+        # reads it there to count each step's work from its shape
         self.last_decode: Optional[Dict] = None
         self._build_metrics()
 
@@ -529,6 +571,22 @@ class Engine:
             ("serving_prefill_chunks_total", "counter",
              lambda: self._prefill_chunks,
              "prefill chunks advanced (EngineStats.prefill_chunks)"),
+            ("serving_chunk_positions_run_total", "counter",
+             lambda: self._chunk_positions_run,
+             "positions computed by chunk steps "
+             "(EngineStats.chunk_positions_run)"),
+            ("serving_chunk_positions_useful_total", "counter",
+             lambda: self._chunk_positions_useful,
+             "chunk-step positions that carry a request "
+             "(EngineStats.chunk_positions_useful)"),
+            ("serving_kv_blocks_walked_total", "counter",
+             lambda: self._kv_blocks_walked,
+             "KV blocks the attention grid walks, per KV head per layer "
+             "(EngineStats.kv_blocks_walked)"),
+            ("serving_kv_blocks_live_total", "counter",
+             lambda: self._kv_blocks_live,
+             "walked KV blocks that hold active rows' data "
+             "(EngineStats.kv_blocks_live)"),
             ("serving_cancellations_total", "counter",
              lambda: self._cancellations,
              "client cancellations (EngineStats.cancellations)"),
@@ -754,49 +812,55 @@ class Engine:
         preempt starved slots), and snapshot active slots / owners /
         positions.  Rejection and deadline marker events are finalized here
         (callbacks fire at plan time) and carried in ``plan.events``."""
-        t_plan = self.clock.now()
-        if self.fault_hook is not None:
-            # fires before any side effect: a raised plan fault leaves the
-            # scheduler untouched and the supervisor simply replans
-            self.fault_hook("plan", {})
-        self.last_decode = None        # stays None if no slot runs
-        events = self.expire_deadlines()
-        admitted, rejected = self.sched.admit()
-        self._finalize_outputs(rejected)
-        events.extend(rejected)
-        if admitted:
-            self._ensure_state()
-            now = self.clock.now()
-            if self.journal is not None:
-                for _, req in admitted:
-                    # advisory (recovery re-admits from scratch anyway):
-                    # buffered until the step's commit fsync
-                    self.journal.log_admit(req.uid)
-            for slot, req in admitted:
-                self._keys = self._keys.at[slot].set(self._request_key(req))
-                # positions covered by trie-shared blocks skip prefill; on a
-                # preemption resume this counts the re-matched progress too
-                self._prefill_skipped += int(self.sched.prefix_lens[slot])
-                t0 = self._submit_ts.get(req.uid)
-                if t0 is not None:
-                    self._queue_wait_ms.observe((now - t0) * 1e3)
-                if self.tracer is not None:
-                    self.tracer.request_admitted(req.uid, now)
-        # plan this step's chunks (may preempt half-prefilled slots whose
-        # growth starves; may stall slots past the prefill budget)
-        chunks = self.sched.next_chunks()
-        active = self.sched.active_slots()
-        stalled = [s for s in active
-                   if self.sched.pending[s] and s not in chunks]
-        owners = {s: self.sched.slots[s].uid for s in active}
-        if self.tracer is not None:
-            self.tracer.plan_span(t_plan, self.clock.now(),
-                                  self._steps_committed, len(active),
-                                  len(chunks))
-        return StepPlan(events=events, active=active, owners=owners,
-                        chunks=chunks, stalled=stalled,
-                        positions=self.sched.positions.astype(np.int32,
-                                                              copy=True))
+        with jax.profiler.TraceAnnotation(
+                PLAN, step=self._steps_committed) as ann:
+            t_plan = self.clock.now()
+            if self.fault_hook is not None:
+                # fires before any side effect: a raised plan fault leaves
+                # the scheduler untouched and the supervisor simply replans
+                self.fault_hook("plan", {})
+            self.last_decode = None        # stays None if no slot runs
+            events = self.expire_deadlines()
+            admitted, rejected = self.sched.admit()
+            self._finalize_outputs(rejected)
+            events.extend(rejected)
+            if admitted:
+                self._ensure_state()
+                now = self.clock.now()
+                if self.journal is not None:
+                    for _, req in admitted:
+                        # advisory (recovery re-admits from scratch anyway):
+                        # buffered until the step's commit fsync
+                        self.journal.log_admit(req.uid)
+                for slot, req in admitted:
+                    self._keys = self._keys.at[slot].set(
+                        self._request_key(req))
+                    # positions covered by trie-shared blocks skip prefill;
+                    # on a preemption resume this counts the re-matched
+                    # progress too
+                    self._prefill_skipped += int(self.sched.prefix_lens[slot])
+                    t0 = self._submit_ts.get(req.uid)
+                    if t0 is not None:
+                        self._queue_wait_ms.observe((now - t0) * 1e3)
+                    if self.tracer is not None:
+                        self.tracer.request_admitted(req.uid, now)
+            # plan this step's chunks (may preempt half-prefilled slots whose
+            # growth starves; may stall slots past the prefill budget)
+            chunks = self.sched.next_chunks()
+            active = self.sched.active_slots()
+            stalled = [s for s in active
+                       if self.sched.pending[s] and s not in chunks]
+            owners = {s: self.sched.slots[s].uid for s in active}
+            if self.tracer is not None:
+                self.tracer.plan_span(t_plan, self.clock.now(),
+                                      self._steps_committed, len(active),
+                                      len(chunks))
+            plan = StepPlan(events=events, active=active, owners=owners,
+                            chunks=chunks, stalled=stalled,
+                            positions=self.sched.positions.astype(np.int32,
+                                                                  copy=True))
+            ann.set_metadata(kind=step_kind(plan))
+        return plan
 
     def plan_spec(self, inflight: InflightStep) -> Optional[StepPlan]:
         """Plan step N+1 *speculatively* while step N (``inflight``) is still
@@ -810,37 +874,40 @@ class Engine:
         nothing attends before it is overwritten.  Declined when admission
         could run instead (waiting requests + a free slot): filling a slot
         beats overlapping one step."""
-        t_plan = self.clock.now()
-        plan = inflight.plan
-        if inflight.tok is None or plan.chunks or plan.stalled:
-            return None                # only pure-decode steps speculate
-        sc = self.sched
-        active = sc.active_slots()
-        if not active or active != plan.active:
-            return None                # a cancel/deadline freed a slot
-        if sc.waiting and any(r is None for r in sc.slots):
-            return None                # admission possible: plan it for real
-        for slot in active:
-            req = sc.slots[slot]
-            if req is None or req.uid != plan.owners.get(slot):
-                return None
-            # the in-flight step appends one token at commit; a row that
-            # deterministically finishes there frees its slot — plan for real
-            if req.num_generated + 1 >= req.params.max_tokens:
-                return None
-            if int(sc.positions[slot]) + 1 > sc.max_len - 1:
-                return None            # capacity finish at commit
-            if not sc.pregrow_decode(slot):
-                return None            # pool starved: let commit preempt
-        positions = sc.positions.astype(np.int32, copy=True)
-        for slot in active:
-            positions[slot] += 1       # where step N+1 writes, post-commit-N
-        if self.tracer is not None:
-            self.tracer.plan_span(t_plan, self.clock.now(),
-                                  self._steps_committed, len(active), 0,
-                                  spec=True)
-        return StepPlan(events=[], active=list(active), owners=dict(plan.owners),
-                        chunks={}, stalled=[], positions=positions, spec=True)
+        step = self._steps_committed + 1     # launched before N commits
+        with jax.profiler.TraceAnnotation(PLAN, step=step, kind="decode"):
+            t_plan = self.clock.now()
+            plan = inflight.plan
+            if inflight.tok is None or plan.chunks or plan.stalled:
+                return None                # only pure-decode steps speculate
+            sc = self.sched
+            active = sc.active_slots()
+            if not active or active != plan.active:
+                return None                # a cancel/deadline freed a slot
+            if sc.waiting and any(r is None for r in sc.slots):
+                return None                # admission possible: plan it
+            for slot in active:
+                req = sc.slots[slot]
+                if req is None or req.uid != plan.owners.get(slot):
+                    return None
+                # the in-flight step appends one token at commit; a row
+                # that deterministically finishes there frees its slot —
+                # plan for real
+                if req.num_generated + 1 >= req.params.max_tokens:
+                    return None
+                if int(sc.positions[slot]) + 1 > sc.max_len - 1:
+                    return None            # capacity finish at commit
+                if not sc.pregrow_decode(slot):
+                    return None            # pool starved: let commit preempt
+            positions = sc.positions.astype(np.int32, copy=True)
+            for slot in active:
+                positions[slot] += 1       # where N+1 writes, post-commit-N
+            if self.tracer is not None:
+                self.tracer.plan_span(t_plan, self.clock.now(), step,
+                                      len(active), 0, spec=True)
+            return StepPlan(events=[], active=list(active),
+                            owners=dict(plan.owners), chunks={}, stalled=[],
+                            positions=positions, spec=True)
 
     def launch_step(self, plan: StepPlan,
                     feed: Optional[InflightStep] = None) -> InflightStep:
@@ -851,28 +918,33 @@ class Engine:
         next step while the device executes.  A speculative plan feeds
         ``feed.tok`` — the previous step's *device* tokens — instead of the
         host-synced ``self._tokens``."""
-        t_launch = self.clock.now()
-        if not plan.active:
-            return InflightStep(plan=plan, tok=None, launched_at=t_launch)
-        if self.fault_hook is not None:
-            # fires before dispatch: a raised launch fault (or injected
-            # slow/hung step) leaves device state untouched — the same plan
-            # relaunches verbatim
-            self.fault_hook("launch", {"plan": plan})
-        self._ensure_state()
-        write_blocks = None
-        if self.shadow is not None:
-            write_blocks = self._sanitize_writes(plan)
-        if plan.chunks or plan.stalled:
-            tok = self._launch_chunk(plan)
-        else:
-            tok = self._launch_decode(plan, feed)
-        launched_at = self.clock.now()
-        if self.tracer is not None:
-            self.tracer.launch_span(t_launch, launched_at,
-                                    self._steps_committed, plan.spec)
-        return InflightStep(plan=plan, tok=tok, launched_at=launched_at,
-                            write_blocks=write_blocks)
+        step = self._steps_committed + int(plan.spec)
+        with jax.profiler.TraceAnnotation(LAUNCH, step=step,
+                                          kind=step_kind(plan)):
+            t_launch = self.clock.now()
+            if not plan.active:
+                return InflightStep(plan=plan, tok=None, launched_at=t_launch,
+                                    step=step)
+            if self.fault_hook is not None:
+                # fires before dispatch: a raised launch fault (or injected
+                # slow/hung step) leaves device state untouched — the same
+                # plan relaunches verbatim
+                self.fault_hook("launch", {"plan": plan})
+            self._ensure_state()
+            write_blocks = None
+            if self.shadow is not None:
+                write_blocks = self._sanitize_writes(plan)
+            if plan.chunks or plan.stalled:
+                tok, work = self._launch_chunk(plan)
+            else:
+                tok, work = self._launch_decode(plan, feed)
+            launched_at = self.clock.now()
+            if self.tracer is not None:
+                self.tracer.launch_span(t_launch, launched_at, step,
+                                        plan.spec)
+            return InflightStep(plan=plan, tok=tok, launched_at=launched_at,
+                                write_blocks=write_blocks, step=step,
+                                work=work)
 
     def commit_step(self, inflight: InflightStep,
                     tok_np: Optional[np.ndarray] = None) -> List[StepOutput]:
@@ -883,89 +955,104 @@ class Engine:
         discarded; budget-stalled rows are skipped.  ``tok_np`` lets the
         async loop pass tokens it already materialized off-thread.  Returns
         the plan's marker events followed by this step's outputs."""
-        plan = inflight.plan
-        sc = self.sched
-        outs: List[StepOutput] = []
-        if inflight.tok is not None:
-            if tok_np is None:
-                # the step's one budgeted device sync
-                tok_np = np.asarray(inflight.tok)  # lint: allow(host-sync)
-            if self.fault_hook is not None:
-                # fires after the sync but before validation/mutation; may
-                # raise an injected device fault or corrupt token rows (the
-                # NaN-logits simulation — replaced array read back from ctx)
-                ctx = {"plan": plan, "tok": tok_np}
-                self.fault_hook("commit", ctx)
-                tok_np = ctx["tok"]
-            # validate *before* any scheduler/request mutation: a failed
-            # step must be side-effect-free so the supervisor can relaunch
-            # the same plan (KV rewrites are (token, position)-determined,
-            # hence bit-identical on replay)
-            self._validate_tokens(plan, tok_np)
-            now = self.clock.now()
-            step_id = self._steps_committed
-            self._steps_committed += 1
-            if self._last_sync is not None:
-                gap = inflight.launched_at - self._last_sync
-                if gap <= 0.0:
-                    self._steps_overlapped += 1
-                self._step_gap_ms.observe(max(0.0, gap) * 1e3)
-            self._last_sync = now
-            for slot in plan.active:
-                req = sc.slots[slot]
-                if req is None or req.uid != plan.owners.get(slot):
-                    continue           # slot freed mid-flight: discard token
-                n = plan.chunks.get(slot)
-                if n is not None:
-                    if not sc.advance_prefill(slot, n):
-                        continue       # still prefilling: no token this step
-                elif slot in plan.stalled:
-                    continue           # budget-stalled: emit-less pad row
-                self._tokens[slot] = int(tok_np[slot])
-                outs.append(sc.record(slot, int(tok_np[slot])))
-            self._prefill_positions += sum(plan.chunks.values())
-            self._prefill_chunks += len(plan.chunks)
-            if (self.shadow is not None and self.shadow.checksums_enabled
-                    and inflight.write_blocks):
-                # refresh the content digest of every block this step wrote
-                # (captured at launch); blocks freed by this commit are
-                # skipped inside note_checksum
-                for b, d in self._kv_block_digests(
-                        inflight.write_blocks).items():
-                    self.shadow.note_checksum(b, d)
-            if self.tracer is not None:
-                # device span: dispatch return -> host-visible sync; the
-                # commit span covers the scheduler application.  One chunk
-                # span per planned chunk keeps counts['prefill_chunk'] ==
-                # EngineStats.prefill_chunks (both count plan.chunks of
-                # committed steps, owner-valid or not), and commit spans
-                # mirror _steps_committed exactly.
-                self.tracer.device_span(inflight.launched_at, now, step_id,
-                                        plan.spec)
-                for slot, n in plan.chunks.items():
-                    self.tracer.prefill_chunk(plan.owners.get(slot, -1),
-                                              inflight.launched_at, now, n)
-                self.tracer.commit_span(now, self.clock.now(), step_id,
-                                        len(outs), len(plan.chunks))
-            if self.recorder is not None:
-                self.recorder.record("commit", step=step_id,
-                                     active=len(plan.active),
-                                     chunks=len(plan.chunks),
-                                     outputs=len(outs), spec=plan.spec)
-        # any slot freed this step (finish, cancel, or paged preemption) must
-        # decode the pad token while idle, not the dead request's last token
-        for slot, req in enumerate(sc.slots):
-            if req is None:
-                self._tokens[slot] = self.scfg.pad_id
-        if self.shadow is not None:
-            # cross-check the mirror against the live allocator every step,
-            # and assert the drained invariant (no OWNED/SHARED blocks) the
-            # moment no work remains
-            self.shadow.verify(self.allocator)
-            if not sc.has_work():
-                self.shadow.assert_drained()
-        self._finalize_outputs(outs)
-        return plan.events + outs
+        with jax.profiler.TraceAnnotation(COMMIT, step=self._steps_committed,
+                                          kind=step_kind(inflight.plan)):
+            plan = inflight.plan
+            sc = self.sched
+            outs: List[StepOutput] = []
+            if inflight.tok is not None:
+                if tok_np is None:
+                    # the step's one budgeted device sync
+                    tok_np = np.asarray(inflight.tok)  # lint: allow(host-sync)
+                if self.fault_hook is not None:
+                    # fires after the sync but before validation/mutation;
+                    # may raise an injected device fault or corrupt token rows
+                    # (the NaN-logits simulation — replaced array read back
+                    # from ctx)
+                    ctx = {"plan": plan, "tok": tok_np}
+                    self.fault_hook("commit", ctx)
+                    tok_np = ctx["tok"]
+                # validate *before* any scheduler/request mutation: a failed
+                # step must be side-effect-free so the supervisor can
+                # relaunch the same plan (KV rewrites are (token,
+                # position)-determined, hence bit-identical on replay)
+                self._validate_tokens(plan, tok_np)
+                now = self.clock.now()
+                step_id = self._steps_committed
+                self._steps_committed += 1
+                if self._last_sync is not None:
+                    gap = inflight.launched_at - self._last_sync
+                    if gap <= 0.0:
+                        self._steps_overlapped += 1
+                    self._step_gap_ms.observe(max(0.0, gap) * 1e3)
+                self._last_sync = now
+                for slot in plan.active:
+                    req = sc.slots[slot]
+                    if req is None or req.uid != plan.owners.get(slot):
+                        continue       # slot freed mid-flight: discard token
+                    n = plan.chunks.get(slot)
+                    if n is not None:
+                        if not sc.advance_prefill(slot, n):
+                            continue   # still prefilling: no token this step
+                    elif slot in plan.stalled:
+                        continue       # budget-stalled: emit-less pad row
+                    self._tokens[slot] = int(tok_np[slot])
+                    outs.append(sc.record(slot, int(tok_np[slot])))
+                self._prefill_positions += sum(plan.chunks.values())
+                self._prefill_chunks += len(plan.chunks)
+                if (self.shadow is not None
+                        and self.shadow.checksums_enabled
+                        and inflight.write_blocks):
+                    # refresh the content digest of every block this step
+                    # wrote (captured at launch); blocks freed by this commit
+                    # are skipped inside note_checksum
+                    for b, d in self._kv_block_digests(
+                            inflight.write_blocks).items():
+                        self.shadow.note_checksum(b, d)
+                w = inflight.work
+                if w.kind == "chunk":
+                    self._chunk_positions_run += w.positions_run
+                    self._chunk_positions_useful += w.positions_useful
+                self._kv_blocks_walked += w.kv_blocks_walked
+                self._kv_blocks_live += w.kv_blocks_live
+                if self.tracer is not None:
+                    # the commit span covers the scheduler application.  One
+                    # chunk span per planned chunk keeps
+                    # counts['prefill_chunk'] == EngineStats.prefill_chunks
+                    # (both count plan.chunks of committed steps, owner-valid
+                    # or not); commit spans and step_work records mirror
+                    # _steps_committed exactly.
+                    for slot, n in plan.chunks.items():
+                        self.tracer.prefill_chunk(plan.owners.get(slot, -1),
+                                                  inflight.launched_at, now,
+                                                  n)
+                    self.tracer.step_work(step_id, w.kind,
+                                          inflight.launched_at, w.rows,
+                                          w.positions_run, w.positions_useful,
+                                          w.kv_blocks_walked,
+                                          w.kv_blocks_live)
+                    self.tracer.commit_span(now, self.clock.now(), step_id,
+                                            len(outs), len(plan.chunks))
+                if self.recorder is not None:
+                    self.recorder.record("commit", step=step_id,
+                                         active=len(plan.active),
+                                         chunks=len(plan.chunks),
+                                         outputs=len(outs), spec=plan.spec)
+            # any slot freed this step (finish, cancel, or paged preemption)
+            # must decode the pad token while idle, not the dead request's
+            # last token
+            for slot, req in enumerate(sc.slots):
+                if req is None:
+                    self._tokens[slot] = self.scfg.pad_id
+            if self.shadow is not None:
+                # cross-check the mirror against the live allocator every
+                # step, and assert the drained invariant (no OWNED/SHARED
+                # blocks) the moment no work remains
+                self.shadow.verify(self.allocator)
+                if not sc.has_work():
+                    self.shadow.assert_drained()
+            self._finalize_outputs(outs)
+            return plan.events + outs
 
     def _validate_tokens(self, plan: StepPlan, tok_np: np.ndarray) -> None:
         """Reject a step whose *consumable* rows carry out-of-range tokens —
@@ -1076,14 +1163,15 @@ class Engine:
                     written.append(b)
         return written
 
-    def _launch_decode(self, plan: StepPlan,
-                       feed: Optional[InflightStep]) -> jax.Array:
+    def _launch_decode(self, plan: StepPlan, feed: Optional[InflightStep]
+                       ) -> Tuple[jax.Array, StepWork]:
         """Pure-decode dispatch (no prefilling slots): the paged_attention
         decode kernel / gather path, one token per active slot."""
-        sc = self.sched
+        sc, b = self.sched, self.scfg.max_batch
         positions = plan.positions
         bt = None
         width = None
+        walked = live = 0
         if self.paged:
             # gather only the blocks covering the deepest active row
             # (power-of-two widths bound retraces, like chunk buckets) —
@@ -1093,9 +1181,13 @@ class Engine:
             width = bucket_length(self.allocator.blocks_for(depth), 1,
                                   sc.block_tables.shape[1])
             bt = jnp.asarray(sc.block_tables[:, :width])
+            walked = b * width
+            live = sum(self.allocator.blocks_for(int(positions[s]) + 1)
+                       for s in plan.active)
+        work = StepWork("decode", len(plan.active), b, len(plan.active),
+                        walked, live)
         # snapshot of the step shape actually run (post-admission,
-        # pre-record): benchmarks/speed_memory.py models per-step KV
-        # traffic from this instead of guessing from advanced state
+        # pre-record), which the serving benchmark counts work from
         self.last_decode = {"active": list(plan.active),
                             "positions": positions.tolist(),
                             "table_width": width,
@@ -1109,9 +1201,9 @@ class Engine:
             self.params, toks_in, self._cache,
             jnp.asarray(positions), self._keys,
             jnp.asarray(sc.temperatures), jnp.asarray(sc.top_ps), bt)
-        return tok
+        return tok, work
 
-    def _launch_chunk(self, plan: StepPlan) -> jax.Array:
+    def _launch_chunk(self, plan: StepPlan) -> Tuple[jax.Array, StepWork]:
         """Fused chunk-step dispatch: prefilling slots advance their planned
         chunk, decoding slots their one token, in a single jitted call.
         Budget-stalled mid-prefill slots ride along as emit-less length-1 pad
@@ -1145,11 +1237,19 @@ class Engine:
                 emit[slot] = True
         bt = None
         width = None
+        walked = live = 0
         if self.paged:
             depth = max(int(start[s]) + int(lens[s]) for s in plan.active)
             width = bucket_length(self.allocator.blocks_for(depth), 1,
                                   sc.block_tables.shape[1])
             bt = jnp.asarray(sc.block_tables[:, :width])
+            walked = scfg.max_batch * width
+            live = sum(self.allocator.blocks_for(int(start[s]) + int(lens[s]))
+                       for s in plan.active)
+        useful = sum(int(lens[s]) for s in plan.active
+                     if s not in plan.stalled)
+        work = StepWork("chunk", len(plan.active), scfg.max_batch * t, useful,
+                        walked, live)
         self.last_decode = {"active": list(plan.active),
                             "positions": start.tolist(),
                             "table_width": width,
@@ -1166,7 +1266,7 @@ class Engine:
             tok, self._cache, self._keys = fn(*args, bt)
         else:
             tok, self._cache, self._keys = self._chunk_scan(*args)
-        return tok
+        return tok, work
 
     # -- cancellation / deadlines ----------------------------------------------
 
@@ -1461,6 +1561,10 @@ class Engine:
             prefill_positions=self._prefill_positions,
             prefill_positions_skipped=self._prefill_skipped,
             prefill_chunks=self._prefill_chunks,
+            chunk_positions_run=self._chunk_positions_run,
+            chunk_positions_useful=self._chunk_positions_useful,
+            kv_blocks_walked=self._kv_blocks_walked,
+            kv_blocks_live=self._kv_blocks_live,
             tokens_generated=self._tokens_generated,
             queue_depth=len(self.sched.waiting),
             cancellations=self._cancellations,

@@ -360,7 +360,9 @@ class ServingSupervisor:
         """Counters are cumulative across restarts: a supervised service
         reports one continuous stats stream, not per-incarnation resets."""
         for attr in ("_prefill_positions", "_prefill_skipped",
-                     "_prefill_chunks", "_ttft_ms", "_queue_wait_ms",
+                     "_prefill_chunks", "_chunk_positions_run",
+                     "_chunk_positions_useful", "_kv_blocks_walked",
+                     "_kv_blocks_live", "_ttft_ms", "_queue_wait_ms",
                      "_e2e_ms", "_step_gap_ms", "_steps_committed",
                      "_steps_overlapped", "_tokens_generated",
                      "_cancellations", "_deadline_expirations",

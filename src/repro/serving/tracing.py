@@ -6,11 +6,13 @@ records two families of spans from timestamps the engine already takes
 through its :class:`~repro.serving.telemetry.Clock`:
 
 * **engine track** (pid 1) — one span per ``plan_step`` /
-  ``launch_step`` / device-busy window / ``commit_step`` call, on
-  separate threads so the async double-buffer overlap is visible: a
-  speculative ``device`` span of step N+1 starts *before* step N's
-  ``commit`` span ends.  The off-thread host sync in
-  ``AsyncEngine._loop`` gets its own ``sync`` track.
+  ``launch_step`` / ``commit_step`` call, on separate threads so the async
+  double-buffer overlap is visible: a speculative ``launch`` of step N+1
+  lands *before* step N's ``commit``.  The off-thread host sync in
+  ``AsyncEngine._loop`` gets its own ``sync`` track.  Each committed step
+  also records its work counts (:meth:`Tracer.step_work`), exported as a
+  ``step_work`` counter track: the share of computed positions that carry
+  a request, and the share of walked KV blocks that hold live data.
 * **request track** (pid 2, one thread per request uid) — the request's
   lifecycle: a ``queued`` span (submit → admission), ``prefill_chunk``
   spans (one per chunk the scheduler advanced in a committed step), a
@@ -25,8 +27,23 @@ Span accounting reconciles exactly with
 :class:`~repro.serving.api.EngineStats`: ``counts["request"]`` ==
 ``requests_submitted``, ``counts["step"]`` == ``steps_committed``,
 ``counts["prefill_chunk"]`` == ``prefill_chunks`` (the benchmark
-``--trace`` mode gates on this).  :meth:`open_requests` must be empty
-after a drained run — an unclosed request span is a lifecycle bug.
+``--trace`` mode gates on this), and the sums over :attr:`Tracer.work`
+equal the step-work counters (``chunk_positions_run``,
+``chunk_positions_useful``, ``kv_blocks_walked``, ``kv_blocks_live``).
+:meth:`open_requests` must be empty after a drained run — an unclosed
+request span is a lifecycle bug.
+
+Names that outside readers match are declared here once:
+
+* the phases the engine writes into a ``jax.profiler`` capture as
+  ``TraceAnnotation`` host spans (``serve.plan`` / ``serve.launch`` /
+  ``serve.sync`` / ``serve.commit``, each with ``step=<id>`` and
+  ``kind=chunk|decode|idle``), so a profile of a serving process shows
+  the engine's phases on the device trace's own clock;
+* the XLA module names of the two jitted step programs and the HLO
+  instruction names of the two Pallas kernels (the kernels pass theirs as
+  ``pallas_call(name=...)``), which profile readers match by name.
+  ``tests/test_chip_compile.py`` pins them against a compile for the chip.
 
 Pure stdlib; no numpy/jax (this module is reachable from the lint's hot
 step path and must stay host-sync-free).  Event storage grows with the
@@ -40,7 +57,22 @@ from typing import Dict, List, Optional
 
 from repro.serving.telemetry import Clock
 
-__all__ = ["Tracer", "PID_ENGINE", "PID_REQUESTS"]
+__all__ = ["Tracer", "PID_ENGINE", "PID_REQUESTS", "PLAN", "LAUNCH",
+           "SYNC", "COMMIT", "DECODE_PROGRAM", "CHUNK_PROGRAM",
+           "DECODE_KERNEL", "PREFILL_KERNEL"]
+
+# host phases in a jax.profiler capture (TraceAnnotation names)
+PLAN = "serve.plan"
+LAUNCH = "serve.launch"
+SYNC = "serve.sync"
+COMMIT = "serve.commit"
+
+# XLA module names of the jitted step programs (Engine._decode_impl,
+# Engine._chunk_step_impl) and HLO instruction names of the Pallas kernels
+DECODE_PROGRAM = "jit__decode_impl"
+CHUNK_PROGRAM = "jit__chunk_step_impl"
+DECODE_KERNEL = "paged_attention_decode_kernel"
+PREFILL_KERNEL = "paged_prefill_chunk_kernel"
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
@@ -48,14 +80,12 @@ PID_REQUESTS = 2
 # engine-track thread ids, ordered the way Perfetto should stack them
 TID_PLAN = 1
 TID_LAUNCH = 2
-TID_DEVICE = 3
-TID_SYNC = 4
-TID_COMMIT = 5
+TID_SYNC = 3
+TID_COMMIT = 4
 
 _ENGINE_THREADS = {
     TID_PLAN: "plan",
     TID_LAUNCH: "launch",
-    TID_DEVICE: "device",
     TID_SYNC: "sync",
     TID_COMMIT: "commit",
 }
@@ -78,6 +108,8 @@ class Tracer:
         self._open: Dict[int, dict] = {}
         self._req_tid: Dict[int, int] = {}
         self._next_req_tid = 1
+        # one tuple per committed step (step_work's arguments, in order)
+        self.work: List[tuple] = []
 
     # -- time ---------------------------------------------------------------
 
@@ -114,12 +146,6 @@ class Tracer:
         self._complete("launch", PID_ENGINE, TID_LAUNCH, t0, t1, "step",
                        {"step": step, "spec": spec})
 
-    def device_span(self, t0, t1, step, spec=False):
-        """Device-busy window: launch dispatch to host-visible sync.  With
-        speculative launch this overlaps the previous step's commit."""
-        self._complete("device", PID_ENGINE, TID_DEVICE, t0, t1, "step",
-                       {"step": step, "spec": spec})
-
     def sync_span(self, t0, t1, step):
         """The off-thread ``np.asarray`` host sync in ``AsyncEngine._loop``."""
         self._complete("sync", PID_ENGINE, TID_SYNC, t0, t1, "step",
@@ -131,6 +157,17 @@ class Tracer:
         self.counts["step"] += 1
         self._complete("commit", PID_ENGINE, TID_COMMIT, t0, t1, "step",
                        {"step": step, "tokens": tokens, "chunks": chunks})
+
+    def step_work(self, step, kind, t_launch, rows, positions_run,
+                  positions_useful, kv_blocks_walked, kv_blocks_live):
+        """Work counts of one committed step (``Engine``'s ``StepWork``,
+        counted from its plan at launch): rows carrying a request; positions
+        every linear layer computed and those that carry a request; KV
+        blocks the attention kernels' grid walks per KV head per layer and
+        those that hold the rows' data.  ``t_launch`` is the engine-clock
+        time dispatch returned."""
+        self.work.append((step, kind, t_launch, rows, positions_run,
+                          positions_useful, kv_blocks_walked, kv_blocks_live))
 
     # -- request track ------------------------------------------------------
 
@@ -211,14 +248,29 @@ class Tracer:
                          "args": {"name": f"req {uid}"}})
         return meta
 
+    def _counters(self) -> List[dict]:
+        """One ``step_work`` counter event per committed step, at its
+        launch: the useful share of computed positions and the live share
+        of walked KV blocks, in percent."""
+        out = []
+        for (_, _, t, _, run, useful, walked, live) in self.work:
+            out.append({"name": "step_work", "cat": "step", "ph": "C",
+                        "ts": self._us(t), "pid": PID_ENGINE,
+                        "tid": TID_LAUNCH,
+                        "args": {"useful_pct": 100.0 * useful / run,
+                                 "live_pct": (100.0 * live / walked
+                                              if walked else 0.0)}})
+        return out
+
     def num_events(self) -> int:
-        return len(self._events)
+        return len(self._events) + len(self.work)
 
     def export(self, path: Optional[str] = None) -> dict:
         """Chrome trace-event JSON; written to ``path`` when given.  Safe
         to call mid-run (exports the events recorded so far)."""
         doc = {
-            "traceEvents": self._metadata() + list(self._events),
+            "traceEvents": (self._metadata() + list(self._events)
+                            + self._counters()),
             "displayTimeUnit": "ms",
             "otherData": {"counts": dict(self.counts),
                           "open_requests": self.open_requests()},

@@ -278,7 +278,6 @@ class TestTracerUnit:
         tr.request_finish(1, 0.004, "length", tokens=4)
         tr.plan_span(0.0, 0.001, step=0, active=1, chunks=1)
         tr.launch_span(0.001, 0.002, step=0)
-        tr.device_span(0.002, 0.003, step=0)
         tr.sync_span(0.003, 0.0035, step=0)
         tr.commit_span(0.0035, 0.004, step=0, tokens=1, chunks=1)
         assert tr.counts["request"] == 1
