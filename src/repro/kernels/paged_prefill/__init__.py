@@ -1,16 +1,19 @@
 """Fused paged-prefill chunk attention: T prompt tokens per step against the
 block pools.
 
-Grid (B, Hkv, L) on the same blocking template as paged_attention's decode
-kernel: the logical-block dim is innermost, an online-softmax (m, z, acc)
-carry for all T*g query rows lives in VMEM scratch across a row's blocks, and
-per-row chunk starts/lengths plus the block table arrive as scalar-prefetch
-operands that drive the pool BlockSpec index maps.  Resident KV (including
-trie-shared prefix blocks — no gather-into-contiguous-cache seeding step) is
-streamed once per (row, kv-head); the chunk's own K/V never round-trips
-through HBM: its causal T x T scores fold into the carry at the last touched
-block and the chunk KV is scatter-written into the row's pool blocks through
-aliased pool outputs.  KV bytes read per chunk step are O(tokens resident),
-not O(B * table_width * block_size).  See kernel.py for the full scheme.
+Each (row, kv-head) fetches only its row's resident real blocks — the
+leading non-trash table entries holding positions before the chunk — and the
+blocks the chunk lands in, and folds them into an online-softmax (m, z, acc)
+carry in VMEM scratch; per-row chunk starts/lengths, the block table and the
+resident block counts arrive as scalar-prefetch operands.  Where the head
+size fills whole 128-lane rows the grid is (B, Hkv) and resident blocks come
+in tiles of up to 128 positions by double-buffered manual DMA; otherwise the
+grid walks the table one block per step through BlockSpec index maps.
+Trie-shared prefix blocks are read in place; the chunk's own K/V never
+round-trips through HBM: its causal T x T scores fold into the carry from
+VMEM and it is spliced into the row's pool blocks through aliased pool
+outputs.  Idle rows (all-trash tables) fetch nothing, so KV bytes read and
+scores computed per chunk step follow the live KV, not
+B * table_width * block_size.  See kernel.py for the full scheme.
 """
 from repro.kernels.paged_prefill import kernel, ops, ref  # noqa: F401

@@ -7,25 +7,28 @@ in-place-updated pools out.  On CPU the kernel runs in interpret mode
 (correctness path; the chunk-gather fallback is what "auto" serving selects
 there).  Inference only — no VJP.
 
-``prefill_kv_bytes`` is the per-chunk-step KV-traffic model shared by
-benchmarks/speed_memory.py and launch/roofline.py: the fused kernel reads
-``O(tokens resident)`` (one pass over each chunked row's resident + touched
-blocks; the chunk's own KV is scored from VMEM), the gather fallback reads
-the dense ``B * table_width * block_size`` window.
+``fetched_blocks`` is the kernel's fetch rule on the host: which table
+entries of each row the kernel copies in per KV head per layer (its resident
+real blocks, scored, and the real blocks its chunk is spliced into).  The
+engine's ``StepWork.kv_blocks_scored`` counts them; ``prefill_kv_bytes``, the
+per-chunk-step KV-traffic model of benchmarks/speed_memory.py, prices them.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
 import jax
+import numpy as np
 
-from repro.kernels.paged_prefill.kernel import paged_prefill_chunk_kernel
+from repro.kernels.paged_prefill.kernel import (paged_prefill_chunk_kernel,
+                                                resident_blocks)
+from repro.serving.paged import TRASH_BLOCK
 
 
 def _interpret_default() -> bool:
     # the kernel uses pltpu-only machinery (PrefetchScalarGridSpec, VMEM
-    # scratch): any non-TPU backend must take the interpreter, not a
-    # doomed native lowering
+    # scratch, DMA semaphores): any non-TPU backend must take the
+    # interpreter, not a doomed native lowering
     return jax.default_backend() != "tpu"
 
 
@@ -61,6 +64,28 @@ def paged_prefill_chunk(q: jax.Array, k_chunk: jax.Array, v_chunk: jax.Array,
     return out.reshape(b, t, hq, dh), k_pool, v_pool
 
 
+def fetched_blocks(start, lens, table, block_size: int
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """The kernel's fetch rule, on the host: bool ``[B, L]`` masks of the
+    table entries each row copies in per KV head per layer — ``resident``,
+    the leading real blocks that hold positions ``< start`` (scored), and
+    ``splice``, the real blocks among ``c0 .. c1`` that the chunk
+    ``[start, start + lens)`` lands in (read, spliced, written back).  An
+    idle row (table all trash) fetches nothing; a block can be in both (a
+    partly filled last block is scored and spliced).  ``start``/``lens``
+    [B] and ``table`` [B, L] are host arrays."""
+    start = np.asarray(start, np.int64)
+    lens = np.asarray(lens, np.int64)
+    table = np.asarray(table)
+    width = table.shape[1]
+    col = np.arange(width)[None]
+    resident = col < resident_blocks(start, table, block_size, np)[:, None]
+    c0 = np.minimum(start // block_size, width - 1)[:, None]
+    c1 = np.minimum((start + lens - 1) // block_size, width - 1)[:, None]
+    splice = (col >= c0) & (col <= c1) & (table != TRASH_BLOCK)
+    return resident, splice
+
+
 def prefill_kv_bytes(starts: Sequence[int], lens: Sequence[int],
                      chunked: Sequence[int], table_width: int,
                      block_size: int, n_kv_heads: int, head_dim: int,
@@ -69,21 +94,18 @@ def prefill_kv_bytes(starts: Sequence[int], lens: Sequence[int],
 
     ``starts``/``lens`` are the per-slot chunk start positions and valid
     lengths, ``chunked`` the slot indices that ran a chunk (prefilling or
-    decoding — both attend), ``table_width`` the bucketed block-table width
-    the engine passed down.  Gather: every slot pays the dense window.
-    Fused: each chunked row streams its resident blocks (plus the partially
-    written blocks the chunk splices) once; idle rows re-read a single trash
-    block; the chunk's own KV is scored from VMEM and never re-read."""
+    decoding — both attend; their tables hold real blocks through the
+    chunk), ``table_width`` the bucketed block-table width the engine passed
+    down.  Gather: every slot pays the dense window.  Fused: the blocks
+    :func:`fetched_blocks` names — each chunked row's resident blocks plus
+    the blocks its chunk is spliced into; idle rows (table all trash) read
+    nothing; the chunk's own KV is scored from VMEM and never re-read."""
     per_token = 2 * n_kv_heads * head_dim * itemsize * n_layers   # K and V
     n_slots = len(starts)
     if not fused:
         return n_slots * table_width * block_size * per_token
-    blocks = 0
-    chunked = set(chunked)
-    for s in range(n_slots):
-        if s in chunked:
-            last = int(starts[s]) + max(int(lens[s]), 1) - 1
-            blocks += min(last // block_size, table_width - 1) + 1
-        else:
-            blocks += 1                       # trash block, fetched once
+    table = np.full((n_slots, table_width), TRASH_BLOCK, np.int32)
+    table[list(chunked)] = TRASH_BLOCK + 1              # any real block
+    blocks = sum(int(m.sum()) for m in fetched_blocks(starts, lens, table,
+                                                       block_size))
     return blocks * block_size * per_token

@@ -142,11 +142,14 @@ class EngineStats:
     the positions chunk steps computed (``max_batch`` x the chunk bucket,
     what every linear layer runs) and ``chunk_positions_useful`` those that
     carried a request (a prefilling row's chunk, a decoding row's token);
-    ``kv_blocks_walked`` counts the KV blocks the attention grid walked per
-    KV head per layer (``max_batch`` x table width, decode and chunk steps)
-    and ``kv_blocks_live`` those holding the active rows' positions.  Their
-    ratios are the fused step's useful share and the attention grid's live
-    share (0 blocks on the contiguous cache).
+    ``kv_blocks_walked`` counts the KV blocks of the tables handed to the
+    attention kernels per KV head per layer (``max_batch`` x table width,
+    decode and chunk steps), ``kv_blocks_live`` those holding the active
+    rows' positions, and ``kv_blocks_scored`` those the kernels fetch (in a
+    fused chunk step each row's resident real blocks plus the blocks its
+    chunk is spliced into, otherwise the whole table).  Their ratios are the
+    fused step's useful share, the tables' live share and the share the
+    kernels score (0 blocks on the contiguous cache).
     ``ttft_ms`` holds time-to-first-token percentiles (mean / p50 / p95 /
     p99, wall-clock from submit to the first sampled token) once any request
     has produced one, else ``None``; ``queue_wait_ms`` the same percentiles
@@ -214,6 +217,7 @@ class EngineStats:
     chunk_positions_useful: int = 0
     kv_blocks_walked: int = 0
     kv_blocks_live: int = 0
+    kv_blocks_scored: int = 0
     tokens_generated: int = 0
     queue_depth: int = 0
     cancellations: int = 0

@@ -301,16 +301,23 @@ class StepWork(NamedTuple):
     computes (``max_batch x chunk bucket``, or ``max_batch`` for decode);
     ``positions_useful``: those that carry a request (a chunk row's chunk,
     a decoding row's one token; budget-stalled pad rows carry none).
-    ``kv_blocks_walked``: the block axis of the attention kernels' grid per
-    KV head per layer (``max_batch x table width``); ``kv_blocks_live``:
+    ``kv_blocks_walked``: the block table handed to the attention kernel,
+    per KV head per layer (``max_batch x table width``); ``kv_blocks_live``:
     the blocks each active row's positions up to this step's last write
-    occupy.  Both block counts are 0 on the contiguous cache."""
+    occupy; ``kv_blocks_scored``: the blocks the kernel fetches per KV head
+    per layer — in a fused chunk step the prefill kernel's rule
+    (``kernels/paged_prefill/ops.fetched_blocks``: each row's resident real
+    blocks, scored, plus the real blocks its chunk is spliced into; an idle
+    row none), otherwise the whole table (the decode kernel and the gather
+    path score every column).  The block counts are 0 on the contiguous
+    cache."""
     kind: str
     rows: int
     positions_run: int
     positions_useful: int
     kv_blocks_walked: int
     kv_blocks_live: int
+    kv_blocks_scored: int
 
 
 @dataclasses.dataclass
@@ -451,12 +458,13 @@ class Engine:
         self._prefill_skipped = 0
         self._prefill_chunks = 0
         # step-work counters (StepWork, summed per committed step): useful
-        # against computed positions over chunk steps, live against walked
-        # KV blocks over steps of both kinds
+        # against computed positions over chunk steps, live and scored
+        # against walked KV blocks over steps of both kinds
         self._chunk_positions_run = 0
         self._chunk_positions_useful = 0
         self._kv_blocks_walked = 0
         self._kv_blocks_live = 0
+        self._kv_blocks_scored = 0
         self._uid_counter = 0
         self._requests: Dict[int, GenerationRequest] = {}   # uid -> in flight
         self._submit_ts: Dict[int, float] = {}   # uid -> submit wall time
@@ -581,12 +589,16 @@ class Engine:
              "(EngineStats.chunk_positions_useful)"),
             ("serving_kv_blocks_walked_total", "counter",
              lambda: self._kv_blocks_walked,
-             "KV blocks the attention grid walks, per KV head per layer "
-             "(EngineStats.kv_blocks_walked)"),
+             "KV blocks of the tables handed to the attention kernels, per "
+             "KV head per layer (EngineStats.kv_blocks_walked)"),
             ("serving_kv_blocks_live_total", "counter",
              lambda: self._kv_blocks_live,
              "walked KV blocks that hold active rows' data "
              "(EngineStats.kv_blocks_live)"),
+            ("serving_kv_blocks_scored_total", "counter",
+             lambda: self._kv_blocks_scored,
+             "KV blocks the attention kernels fetch, per KV head per layer "
+             "(EngineStats.kv_blocks_scored)"),
             ("serving_cancellations_total", "counter",
              lambda: self._cancellations,
              "client cancellations (EngineStats.cancellations)"),
@@ -1015,6 +1027,7 @@ class Engine:
                     self._chunk_positions_useful += w.positions_useful
                 self._kv_blocks_walked += w.kv_blocks_walked
                 self._kv_blocks_live += w.kv_blocks_live
+                self._kv_blocks_scored += w.kv_blocks_scored
                 if self.tracer is not None:
                     # the commit span covers the scheduler application.  One
                     # chunk span per planned chunk keeps
@@ -1031,6 +1044,9 @@ class Engine:
                                           w.positions_run, w.positions_useful,
                                           w.kv_blocks_walked,
                                           w.kv_blocks_live)
+                    self.tracer.step_scored(step_id, w.kind,
+                                            inflight.launched_at,
+                                            w.kv_blocks_scored)
                     self.tracer.commit_span(now, self.clock.now(), step_id,
                                             len(outs), len(plan.chunks))
                 if self.recorder is not None:
@@ -1185,7 +1201,7 @@ class Engine:
             live = sum(self.allocator.blocks_for(int(positions[s]) + 1)
                        for s in plan.active)
         work = StepWork("decode", len(plan.active), b, len(plan.active),
-                        walked, live)
+                        walked, live, walked)
         # snapshot of the step shape actually run (post-admission,
         # pre-record), which the serving benchmark counts work from
         self.last_decode = {"active": list(plan.active),
@@ -1237,19 +1253,27 @@ class Engine:
                 emit[slot] = True
         bt = None
         width = None
-        walked = live = 0
+        walked = live = scored = 0
         if self.paged:
             depth = max(int(start[s]) + int(lens[s]) for s in plan.active)
             width = bucket_length(self.allocator.blocks_for(depth), 1,
                                   sc.block_tables.shape[1])
-            bt = jnp.asarray(sc.block_tables[:, :width])
-            walked = scfg.max_batch * width
+            table = sc.block_tables[:, :width]
+            bt = jnp.asarray(table)
+            walked = scored = scfg.max_batch * width
             live = sum(self.allocator.blocks_for(int(start[s]) + int(lens[s]))
                        for s in plan.active)
+            if self.attn_impl == "fused" and scfg.prefill_chunk > 0:
+                # the prefill kernel's fetch rule (imported here, as
+                # nn/attention.py imports the kernel: the kernel layer
+                # imports serving modules)
+                from repro.kernels.paged_prefill.ops import fetched_blocks
+                scored = sum(int(m.sum()) for m in fetched_blocks(
+                    start, lens, table, self.allocator.block_size))
         useful = sum(int(lens[s]) for s in plan.active
                      if s not in plan.stalled)
         work = StepWork("chunk", len(plan.active), scfg.max_batch * t, useful,
-                        walked, live)
+                        walked, live, scored)
         self.last_decode = {"active": list(plan.active),
                             "positions": start.tolist(),
                             "table_width": width,
@@ -1565,6 +1589,7 @@ class Engine:
             chunk_positions_useful=self._chunk_positions_useful,
             kv_blocks_walked=self._kv_blocks_walked,
             kv_blocks_live=self._kv_blocks_live,
+            kv_blocks_scored=self._kv_blocks_scored,
             tokens_generated=self._tokens_generated,
             queue_depth=len(self.sched.waiting),
             cancellations=self._cancellations,

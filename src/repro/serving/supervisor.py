@@ -362,9 +362,10 @@ class ServingSupervisor:
         for attr in ("_prefill_positions", "_prefill_skipped",
                      "_prefill_chunks", "_chunk_positions_run",
                      "_chunk_positions_useful", "_kv_blocks_walked",
-                     "_kv_blocks_live", "_ttft_ms", "_queue_wait_ms",
-                     "_e2e_ms", "_step_gap_ms", "_steps_committed",
-                     "_steps_overlapped", "_tokens_generated",
+                     "_kv_blocks_live", "_kv_blocks_scored", "_ttft_ms",
+                     "_queue_wait_ms", "_e2e_ms", "_step_gap_ms",
+                     "_steps_committed", "_steps_overlapped",
+                     "_tokens_generated",
                      "_cancellations", "_deadline_expirations",
                      "_requests_submitted",
                      "_step_failures", "_step_retries", "_quarantines",

@@ -12,7 +12,10 @@ through its :class:`~repro.serving.telemetry.Clock`:
   ``AsyncEngine._loop`` gets its own ``sync`` track.  Each committed step
   also records its work counts (:meth:`Tracer.step_work`), exported as a
   ``step_work`` counter track: the share of computed positions that carry
-  a request, and the share of walked KV blocks that hold live data.
+  a request, and the share of walked KV blocks that hold live data; and
+  the KV blocks the attention kernels fetched (:meth:`Tracer.step_scored`),
+  exported as a ``kv_scored`` counter track: their share of the walked
+  blocks.
 * **request track** (pid 2, one thread per request uid) — the request's
   lifecycle: a ``queued`` span (submit → admission), ``prefill_chunk``
   spans (one per chunk the scheduler advanced in a committed step), a
@@ -29,7 +32,8 @@ Span accounting reconciles exactly with
 ``counts["prefill_chunk"]`` == ``prefill_chunks`` (the benchmark
 ``--trace`` mode gates on this), and the sums over :attr:`Tracer.work`
 equal the step-work counters (``chunk_positions_run``,
-``chunk_positions_useful``, ``kv_blocks_walked``, ``kv_blocks_live``).
+``chunk_positions_useful``, ``kv_blocks_walked``, ``kv_blocks_live``), and
+those over :attr:`Tracer.scored` equal ``kv_blocks_scored``.
 :meth:`open_requests` must be empty after a drained run — an unclosed
 request span is a lifecycle bug.
 
@@ -110,6 +114,8 @@ class Tracer:
         self._next_req_tid = 1
         # one tuple per committed step (step_work's arguments, in order)
         self.work: List[tuple] = []
+        # one tuple per committed step (step_scored's arguments, in order)
+        self.scored: List[tuple] = []
 
     # -- time ---------------------------------------------------------------
 
@@ -168,6 +174,12 @@ class Tracer:
         time dispatch returned."""
         self.work.append((step, kind, t_launch, rows, positions_run,
                           positions_useful, kv_blocks_walked, kv_blocks_live))
+
+    def step_scored(self, step, kind, t_launch, kv_blocks_scored):
+        """KV blocks the attention kernels of one committed step fetch per
+        KV head per layer (``StepWork.kv_blocks_scored``), beside the same
+        step's :meth:`step_work` record."""
+        self.scored.append((step, kind, t_launch, kv_blocks_scored))
 
     # -- request track ------------------------------------------------------
 
@@ -249,21 +261,31 @@ class Tracer:
         return meta
 
     def _counters(self) -> List[dict]:
-        """One ``step_work`` counter event per committed step, at its
-        launch: the useful share of computed positions and the live share
-        of walked KV blocks, in percent."""
+        """One ``step_work`` and one ``kv_scored`` counter event per
+        committed step, at its launch: the useful share of computed
+        positions, the live share of walked KV blocks, and the share of
+        walked KV blocks the kernels fetched, in percent."""
         out = []
-        for (_, _, t, _, run, useful, walked, live) in self.work:
+        walked_of = {}
+        for (step, _, t, _, run, useful, walked, live) in self.work:
+            walked_of[step] = walked
             out.append({"name": "step_work", "cat": "step", "ph": "C",
                         "ts": self._us(t), "pid": PID_ENGINE,
                         "tid": TID_LAUNCH,
                         "args": {"useful_pct": 100.0 * useful / run,
                                  "live_pct": (100.0 * live / walked
                                               if walked else 0.0)}})
+        for (step, _, t, scored) in self.scored:
+            walked = walked_of.get(step, 0)
+            out.append({"name": "kv_scored", "cat": "step", "ph": "C",
+                        "ts": self._us(t), "pid": PID_ENGINE,
+                        "tid": TID_LAUNCH,
+                        "args": {"scored_pct": (100.0 * scored / walked
+                                                if walked else 0.0)}})
         return out
 
     def num_events(self) -> int:
-        return len(self._events) + len(self.work)
+        return len(self._events) + len(self.work) + len(self.scored)
 
     def export(self, path: Optional[str] = None) -> dict:
         """Chrome trace-event JSON; written to ``path`` when given.  Safe

@@ -36,8 +36,9 @@ from repro.kernels.paged_prefill.kernel import paged_prefill_chunk_kernel
 
 BLOCK, CHUNK = 16, 32                       # KV block size, prefill chunk
 # (slots, block-table width): chip_smoke.py's served width (64 + 32 tokens),
-# the engine's default max_len of 256, and a wide batch with a long table
-TABLES = [(8, 6), (8, 16), (64, 256)]
+# the engine's default max_len of 256, the benchmark's chat cell (64 slots,
+# 128 blocks), and a wide batch with a long table
+TABLES = [(8, 6), (8, 16), (64, 128), (64, 256)]
 CACHE_DTYPE = jnp.float32                   # ServeConfig.cache_dtype
 COMPUTE_DTYPE = jnp.bfloat16                # ModelConfig.compute_dtype
 

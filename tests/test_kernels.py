@@ -9,7 +9,8 @@ from repro.core.distill import attention_relation_loss
 from repro.kernels.bitlinear import ops as bl_ops, ref as bl_ref
 from repro.kernels.bitlinear.kernel import bitlinear_kernel
 from repro.kernels.paged_attention import ops as pa_ops, ref as pa_ref
-from repro.kernels.paged_prefill import ops as pp_ops, ref as pp_ref
+from repro.kernels.paged_prefill import (kernel as pp_kernel, ops as pp_ops,
+                                        ref as pp_ref)
 from repro.kernels.relation_kd import ops as rk_ops, ref as rk_ref
 from repro.kernels.relation_kd.kernel import relation_kl_rows_kernel
 from repro.kernels.ssd_scan import ops as ssd_ops
@@ -212,9 +213,14 @@ class TestPagedAttentionDecode:
 
 
 def _prefill_case(B, Hq, Hkv, Dh, bs, L, T, starts, lens, softcap=0.0,
-                  trash_rows=(), seed=0):
+                  trash_rows=(), seed=0, shared=0, poison=False, table=None):
     """Build a chunked paged-prefill problem with exclusively-owned blocks
-    per live row covering [0, start + len) and run kernel + ref.
+    per live row covering [0, start + len) and run kernel + ref.  Rows after
+    the first live one read its first ``shared`` blocks in place (a
+    trie-shared prefix); ``table`` replaces the built block table.  With
+    ``poison`` every pool block the kernel's rule (``ops.fetched_blocks``)
+    does not fetch holds NaN on the kernel's side only, so reading one shows
+    in its output.
 
     Returns (kernel outs, ref outs, live row indices)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -226,25 +232,42 @@ def _prefill_case(B, Hq, Hkv, Dh, bs, L, T, starts, lens, softcap=0.0,
     vc = jax.random.normal(ks[4], (B, T, Hkv, Dh), jnp.float32)
     bt = np.zeros((B, L), np.int32)       # unallocated entries -> trash (0)
     nxt = 1
-    for b in range(B):
-        if b in trash_rows:
-            continue
+    live = [b for b in range(B) if b not in trash_rows]
+    for b in live:
         last = min((starts[b] + lens[b] - 1) // bs, L - 1)
         for j in range(last + 1):
-            bt[b, j] = nxt
-            nxt += 1
+            if b != live[0] and j < shared:
+                bt[b, j] = bt[live[0], j]
+            else:
+                bt[b, j] = nxt
+                nxt += 1
+    if table is not None:
+        bt = np.asarray(table, np.int32)
     start = jnp.asarray(starts, jnp.int32)
     ln = jnp.asarray(lens, jnp.int32)
+    kk, kv = k_pool, v_pool
+    if poison:
+        fetched = np.zeros(n_blocks, bool)
+        for mask in pp_ops.fetched_blocks(starts, lens, bt, bs):
+            fetched[bt[mask]] = True
+        kk = jnp.where(fetched[:, None, None, None], k_pool, jnp.nan)
+        kv = jnp.where(fetched[:, None, None, None], v_pool, jnp.nan)
     bt = jnp.asarray(bt)
-    got = pp_ops.paged_prefill_chunk(q, kc, vc, k_pool, v_pool, bt, start,
-                                     ln, softcap=softcap, interpret=True)
+    got = pp_ops.paged_prefill_chunk(q, kc, vc, kk, kv, bt, start, ln,
+                                     softcap=softcap, interpret=True)
+    if poison:
+        # blocks the rule does not fetch are not written either (trash
+        # aside, where colliding discards may land)
+        for out in got[1:]:
+            assert np.isnan(np.asarray(out)[1:][~fetched[1:]]).all()
+        got = (got[0],) + tuple(jnp.where(fetched[:, None, None, None], o, p)
+                                for o, p in zip(got[1:], (k_pool, v_pool)))
     g = Hq // Hkv
     qg = (q.reshape(B, T, Hkv, g, Dh).transpose(0, 2, 1, 3, 4)
           .reshape(B, Hkv, T * g, Dh))
     want = pp_ref.paged_prefill_chunk_ref(
         qg, kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3), k_pool,
         v_pool, bt, start, ln, 1.0 / (Dh ** 0.5), softcap)
-    live = [b for b in range(B) if b not in trash_rows]
     return got, want, live
 
 
@@ -322,9 +345,10 @@ class TestPagedPrefillChunk:
         _assert_prefill_parity(got, want, live, [4, 4])
 
     def test_kv_bytes_model_resident_vs_dense(self):
-        """The traffic model the benchmark/roofline report: fused streams
-        blocks up to each chunked row's last touched block (+1 trash fetch
-        per idle row), gather reads the dense window for every slot."""
+        """The traffic model the benchmark/roofline report: fused reads the
+        blocks the kernel's rule fetches (each chunked row's resident blocks
+        plus the blocks its chunk is spliced into; an idle row nothing),
+        gather reads the dense window for every slot."""
         kw = dict(table_width=8, block_size=8, n_kv_heads=2, head_dim=32,
                   n_layers=2, itemsize=4)
         per_tok = 2 * 2 * 32 * 4 * 2
@@ -334,10 +358,69 @@ class TestPagedPrefillChunk:
                                         **kw)
         dense = pp_ops.prefill_kv_bytes(starts, lens, [0, 1, 2], fused=False,
                                         **kw)
-        # rows stream blocks 0..(start+len-1)//bs: 1 + 4 + 6, plus 1 trash
-        assert fused == (1 + 4 + 6 + 1) * 8 * per_tok
+        # resident ceil(start/bs) + splice blocks: (1+1) + (3+1) + (5+1) + 0
+        assert fused == (2 + 4 + 6 + 0) * 8 * per_tok
         assert dense == 4 * 8 * 8 * per_tok
         assert fused < dense
+
+    # (bs, L, T, starts, lens, trash_rows, shared): bs 16 gives P = 8 blocks
+    # per tile (128 positions), or the table width where it is narrower
+    RULE_CASES = {
+        # idle rows parked at max_len - 1 with all-trash tables
+        "idle_rows_parked": (16, 4, 8, [20, 4095, 5, 4095], [8, 1, 3, 1],
+                             (1, 3), 0),
+        # resident prefix ending mid-tile (and mid-block) of a second tile
+        "prefix_ends_mid_tile": (16, 16, 8, [168, 50], [8, 1], (), 0),
+        # table widths below P
+        "width_1": (16, 1, 8, [3, 0], [8, 5], (), 0),
+        "width_2": (16, 2, 8, [20, 9], [8, 1], (), 0),
+        "width_4": (16, 4, 8, [40, 63], [8, 1], (), 0),
+        # chunk crossing a tile boundary (blocks 7 -> 8)
+        "chunk_crosses_tile": (16, 16, 8, [124, 128], [8, 8], (), 0),
+        # three rows reading a trie-shared 3-block prefix in place
+        "prefix_shared": (16, 8, 8, [53, 48, 60], [4, 8, 1], (), 3),
+    }
+
+    @pytest.mark.parametrize("dh", [128, 64])
+    @pytest.mark.parametrize("case", sorted(RULE_CASES))
+    def test_fetch_rule_cases(self, case, dh):
+        """Each case against the reference, with every block the kernel's
+        rule does not fetch poisoned: ``Dh`` 128 runs the tiled manual-DMA
+        kernel, 64 the BlockSpec walk."""
+        bs, L, T, starts, lens, trash_rows, shared = self.RULE_CASES[case]
+        got, want, live = _prefill_case(len(starts), 4, 2, dh, bs, L, T,
+                                        starts, lens, trash_rows=trash_rows,
+                                        shared=shared, poison=True)
+        _assert_prefill_parity(got, want, live, lens)
+        assert np.isfinite(np.asarray(got[0])).all()
+
+    def test_fetch_rule_counts_a_hand_built_plan(self):
+        """The host count behind ``StepWork.kv_blocks_scored``: an idle row
+        fetches nothing; a row with 43 resident blocks fetches them plus
+        the blocks its chunk is spliced into; a pad row whose write block
+        is trash fetches only its resident blocks.  The kernel, run on the
+        same plan with every other block poisoned, reads no other block."""
+        bs, width, t = 16, 128, 32
+        starts = [4095, 688, 690, 112]
+        lens = [1, 1, 32, 1]
+        table = np.zeros((4, width), np.int32)
+        nxt = 1
+        for b, n in ((1, 44), (2, 46), (3, 7)):    # row 3 stops at block 6
+            table[b, :n] = np.arange(nxt, nxt + n)
+            nxt += n
+        resident, splice = pp_ops.fetched_blocks(starts, lens, table, bs)
+        assert resident.sum(1).tolist() == [0, 43, 44, 7]
+        assert splice.sum(1).tolist() == [0, 1, 3, 0]
+        np.testing.assert_array_equal(
+            np.asarray(pp_kernel.resident_blocks(
+                jnp.asarray(starts), jnp.asarray(table), bs)),
+            resident.sum(1))
+        # the kernel on the same plan, every other block poisoned.  Row 3's
+        # write is a discard to trash, where the reference's pad writes
+        # collide with it: its output is left out, its pool blocks are not
+        got, want, _ = _prefill_case(4, 1, 1, 128, bs, width, t, starts,
+                                     lens, poison=True, table=table)
+        _assert_prefill_parity(got, want, [1, 2], lens)
 
 
 class TestSSDScan:

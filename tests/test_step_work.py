@@ -2,9 +2,10 @@
 
 Each fused step's work is counted on the host from its plan at launch —
 positions every linear layer computes against those carrying a request,
-KV blocks the attention grid walks against those holding live rows — and
-added once per committed step to ``EngineStats``, the metrics registry and
-``Tracer.step_work``.  These tests recompute the counters from each
+KV blocks of the table handed to the attention kernel against those holding
+live rows and those the kernel fetches — and added once per committed step
+to ``EngineStats``, the metrics registry, ``Tracer.step_work`` and
+``Tracer.step_scored``.  These tests recompute the counters from each
 launch's step shape (``Engine.last_decode``, read when ``launch_span``
 fires, as the serving benchmark reads it), in the sync and the async loop,
 under supervisor retries and restarts, and check that a ``jax.profiler``
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.analysis import check_trace
+from repro.kernels.paged_prefill.ops import fetched_blocks
 from repro.models import build_model, get_config
 from repro.serving.api import SamplingParams
 from repro.serving.async_engine import AsyncEngine, drive_requests
@@ -29,7 +31,7 @@ from repro.serving.tracing import COMMIT, LAUNCH, PLAN, SYNC, Tracer
 
 SCFG = dict(max_batch=3, max_len=48, kv_block_size=4, prefill_chunk=4)
 COUNTERS = ("chunk_positions_run", "chunk_positions_useful",
-            "kv_blocks_walked", "kv_blocks_live")
+            "kv_blocks_walked", "kv_blocks_live", "kv_blocks_scored")
 
 
 @pytest.fixture(scope="module")
@@ -40,8 +42,9 @@ def lm():
 
 
 class ShapeTracer(Tracer):
-    """Keeps each launch's step shape under the step id it commits as; a
-    retried step's relaunch replaces its first launch."""
+    """Keeps each launch's step shape, and the block table it was handed,
+    under the step id it commits as; a retried step's relaunch replaces its
+    first launch."""
 
     def __init__(self, engine_of, clock):
         super().__init__(clock=clock)
@@ -52,22 +55,33 @@ class ShapeTracer(Tracer):
     def launch_span(self, t0, t1, step, spec=False):
         super().launch_span(t0, t1, step, spec)
         self.launched += 1
-        self.shapes[step] = dict(self.engine_of().last_decode)
+        eng = self.engine_of()
+        shape = dict(eng.last_decode)
+        shape["table"] = eng.sched.block_tables[:, :shape["table_width"]].copy()
+        self.shapes[step] = shape
 
 
-def recount(shapes, max_batch, block):
-    """The four counters, from launch shapes alone."""
+def recount(shapes, max_batch, block, fused=False):
+    """The five counters, from launch shapes and tables alone: a fused
+    chunk step fetches what the prefill kernel's rule names, any other
+    step the whole table."""
     out = dict.fromkeys(COUNTERS, 0)
     for s in shapes:
         active = s["active"]
-        out["kv_blocks_walked"] += max_batch * s["table_width"]
+        walked = max_batch * s["table_width"]
+        out["kv_blocks_walked"] += walked
         if s["chunks"] is None:                      # decode step
             ends = [s["positions"][i] + 1 for i in active]
+            out["kv_blocks_scored"] += walked
         else:
             out["chunk_positions_run"] += max_batch * s["chunk_t"]
             out["chunk_positions_useful"] += sum(s["lens"][i]
                                                  for i in active)
             ends = [s["starts"][i] + s["lens"][i] for i in active]
+            out["kv_blocks_scored"] += (
+                sum(int(m.sum()) for m in fetched_blocks(
+                    s["starts"], s["lens"], s["table"], block))
+                if fused else walked)
         out["kv_blocks_live"] += sum(math.ceil(e / block) for e in ends)
     return out
 
@@ -92,7 +106,8 @@ def _check(eng, tracer):
     st = eng.stats()
     assert sorted(tracer.shapes) == list(range(st.steps_committed))
     want = recount([tracer.shapes[k] for k in sorted(tracer.shapes)],
-                   eng.scfg.max_batch, eng.scfg.kv_block_size)
+                   eng.scfg.max_batch, eng.scfg.kv_block_size,
+                   fused=eng.attn_impl == "fused")
     assert {k: getattr(st, k) for k in COUNTERS} == want
     snap = eng.metrics.snapshot()
     assert {k: snap[f"serving_{k}_total"] for k in COUNTERS} == want
@@ -103,6 +118,9 @@ def _check(eng, tracer):
     assert sum(w[5] for w in chunk) == st.chunk_positions_useful
     assert sum(w[6] for w in tracer.work) == st.kv_blocks_walked
     assert sum(w[7] for w in tracer.work) == st.kv_blocks_live
+    # Tracer.scored: one record per committed step, beside its work record
+    assert [w[:3] for w in tracer.scored] == [w[:3] for w in tracer.work]
+    assert sum(w[3] for w in tracer.scored) == st.kv_blocks_scored
     kinds = {s["chunks"] is None for s in tracer.shapes.values()}
     assert kinds == {True, False}                    # decode and chunk ran
     assert 0 < st.chunk_positions_useful <= st.chunk_positions_run
@@ -182,6 +200,25 @@ def test_counters_carry_across_a_restart(lm):
     _check(new, tracer)
 
 
+def test_fused_chunk_steps_count_the_kernels_fetch(lm):
+    """With the fused kernels a chunk step counts the blocks the prefill
+    kernel fetches (its rule, from the table it was handed): the idle
+    slot's row fetches nothing, so a chunk step fetches fewer blocks than
+    the table holds and at least the blocks its rows' positions occupy."""
+    cfg, params = lm
+    eng = Engine(cfg, params, ServeConfig(**SCFG, attn_impl="fused"))
+    tracer = ShapeTracer(lambda: eng, eng.clock)
+    eng.tracer = tracer
+    for p, sp in _workload(6, n=SCFG["max_batch"] - 1, max_tokens=4):
+        eng.submit(p, sp)
+    for _ in eng.stream():
+        pass
+    _check(eng, tracer)
+    chunk = [(w, sc) for w, sc in zip(tracer.work, tracer.scored)
+             if w[1] == "chunk"]
+    assert chunk and all(w[7] <= sc[3] < w[6] for w, sc in chunk)
+
+
 def test_budget_stalled_rows_are_not_useful(lm):
     """A mid-prefill row past the step's prefill budget rides the chunk
     step as a pad row: computed, walked, but not useful."""
@@ -227,11 +264,16 @@ def test_export_writes_the_work_as_counter_events(lm):
     doc = tracer.export()
     assert check_trace(doc) == []
     counters = [e for e in doc["traceEvents"] if e["ph"] == "C"]
-    assert len(counters) == eng.stats().steps_committed
-    for e, w in zip(counters, tracer.work):
+    work = [e for e in counters if e["name"] == "step_work"]
+    scored = [e for e in counters if e["name"] == "kv_scored"]
+    assert len(work) == len(scored) == eng.stats().steps_committed
+    assert len(counters) == len(work) + len(scored)
+    for e, w in zip(work, tracer.work):
         run, useful, walked, live = w[4:]
         assert e["args"]["useful_pct"] == pytest.approx(100 * useful / run)
         assert e["args"]["live_pct"] == pytest.approx(100 * live / walked)
+    for e, w, sc in zip(scored, tracer.work, tracer.scored):
+        assert e["args"]["scored_pct"] == pytest.approx(100 * sc[3] / w[6])
     assert len([e for e in doc["traceEvents"] if e["ph"] != "M"]) == \
         tracer.num_events()
 
