@@ -3,11 +3,15 @@
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix and lists its metrics;
 ``<bench>/configs/<config>.json`` holds the model and serve settings,
-``<bench>/traffic/<mix>.json`` the mix, ``<bench>/cells/<workload>.json``
-what is fixed per cell (open-loop rate and warm start, correctness limit),
-and
-``<bench>/metrics/<metric>.py`` one reader per metric.  Adding a cell, a
-configuration, a mix or a metric adds files; no code here names one.
+``<bench>/arch/<family>.py`` the architecture module the configuration
+names (its ``"arch"`` key): the program's configuration, the seeded
+tensors and their program leaves, the reference's layers and the work
+counts, so that nothing else here reads an architecture's keys or tensor
+names; ``<bench>/traffic/<mix>.json`` the mix,
+``<bench>/cells/<workload>.json`` what is fixed per cell (open-loop rate
+and warm start, correctness limit), and ``<bench>/metrics/<metric>.py`` one
+reader per metric.  Adding a cell, a configuration, an architecture
+family, a mix or a metric adds files; no code here names one.
 
 One process owns the chip.  It builds the program's engine with weights made
 from the seed on the device, warms every step shape the mix can reach, then
@@ -26,7 +30,9 @@ import json
 import math
 import os
 import pathlib
+import re
 import time
+import types
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -112,6 +118,47 @@ def load_reader(bench: pathlib.Path, name: str) -> Callable:
     return mod.read
 
 
+_FAMILIES: Dict[pathlib.Path, types.ModuleType] = {}
+
+
+def load_family(arch_dir: pathlib.Path, name: str) -> types.ModuleType:
+    """The architecture module ``<arch_dir>/<name>.py``, loaded by path once
+    per process (a module may load a sibling it builds on the same way).
+
+    It gives ``architecture(config)``, the architecture's numbers
+    (``arch``) in the configuration file; ``program_config(config)``, the
+    program's ModelConfig for them (before the form's dtypes and
+    quantization); ``layer_kinds(arch)``, the kind of each layer;
+    ``layer_tensors(key, layer, arch, form, kind)`` and ``top_tensors(key,
+    arch, form)``, the seeded tensors by the architecture's names;
+    ``program_leaf(path, arch)``, the layers and tensor name of a program
+    leaf; the float32 reference's ``embed``, ``reference_layer``,
+    ``final_norm`` and ``head_table``; and the work counts
+    ``linear_params``, ``attention_ops``, ``head_ops``,
+    ``kv_bytes_per_token`` and ``attention_io_bytes``."""
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", name):
+        raise BadCell(f"architecture module name {name!r}")
+    path = (pathlib.Path(arch_dir) / f"{name}.py").resolve()
+    if path not in _FAMILIES:
+        if not path.is_file():
+            raise BadCell(f"no architecture module {path}")
+        spec = importlib.util.spec_from_file_location(
+            f"bench_arch_{name.replace('.', '_')}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
+
+
+def family_of(config: Dict, bench: pathlib.Path = BENCH) -> types.ModuleType:
+    """The architecture module a configuration names; a configuration that
+    names none is an error."""
+    if "arch" not in config:
+        raise BadCell("the configuration names no architecture module "
+                      "(its \"arch\" key)")
+    return load_family(bench / "arch", config["arch"])
+
+
 # -- the device ----------------------------------------------------------------
 
 def check_chip(chips: int) -> List:
@@ -175,20 +222,12 @@ class CompileLog:
 
 # -- the program ---------------------------------------------------------------
 
-def model_config(config: Dict):
-    """The program's ModelConfig for a configuration file: the registry's
-    entry with the file's architecture numbers written over it, so the file
-    is the whole truth of what runs."""
+def model_config(config: Dict, bench: pathlib.Path = BENCH):
+    """The program's ModelConfig for a configuration file: the architecture
+    module's, in the file's dtypes and form."""
     from repro.core import quant as Q
-    from repro.models.base import get_config
 
-    a = config["architecture"]
-    cfg = get_config(config["program_arch"]).replace(
-        vocab=a["vocab_size"], d_model=a["hidden_size"],
-        n_layers=a["num_hidden_layers"], n_heads=a["num_attention_heads"],
-        n_kv_heads=a["num_key_value_heads"], head_dim=a["head_dim"],
-        d_ff=a["intermediate_size"], qkv_bias=a["attention_bias"],
-        rope_theta=a["rope_theta"], tie_embeddings=a["tie_word_embeddings"],
+    cfg = family_of(config, bench).program_config(config).replace(
         param_dtype=config["dtypes"]["params"],
         compute_dtype=config["dtypes"]["compute"])
     if config["form"] == "packed":
@@ -198,7 +237,7 @@ def model_config(config: Dict):
     return cfg
 
 
-def build_engine(config: Dict, seed: int):
+def build_engine(config: Dict, seed: int, bench: pathlib.Path = BENCH):
     import jax
     from repro.core.quant import pack_ternary
     from repro.models import build_model
@@ -206,10 +245,11 @@ def build_engine(config: Dict, seed: int):
 
     from .weights import program_params
 
-    cfg = model_config(config)
+    cfg = model_config(config, bench)
     shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
-    params = program_params(seed, config["architecture"], config["form"],
-                            shapes, pack_ternary)
+    family = family_of(config, bench)
+    params = program_params(seed, family, family.architecture(config),
+                            config["form"], shapes, pack_ternary)
     return Engine(cfg, params, ServeConfig(**config["serve"]))
 
 
@@ -362,8 +402,9 @@ class Run:
     window: tuple                    # host perf_counter seconds [w0, w1)
     setup_s: float
     stats: Dict[str, int]            # EngineStats counters over the window
-    arch: Dict
+    arch: Dict                       # the configuration's architecture
     form: str
+    family: types.ModuleType         # its architecture module
     serve: Dict
     kv_itemsize: int                 # bytes per element of the KV pool
     peaks: Optional[Dict] = None
@@ -411,7 +452,7 @@ def _percentile(values, q) -> Optional[float]:
 
 
 async def _drive(eng, cell: Cell, seed: int, seconds: float, trace_dir,
-                 t_start: float, vocab: int, compile_log: CompileLog):
+                 t_start: float, compile_log: CompileLog):
     """Serve the mix through the front end; returns records and timings."""
     import jax
     from repro.serving.async_engine import AsyncEngine
@@ -454,7 +495,7 @@ async def _drive(eng, cell: Cell, seed: int, seconds: float, trace_dir,
             if loop_kind == "open":
                 asks = traffic_mod.open_loop(
                     mix, float(cell.cell["rate_rps"]),
-                    (ramp, seconds, TAIL_S), seed, vocab,
+                    (ramp, seconds, TAIL_S), seed, eng.cfg.vocab,
                     cell.cell.get("token_s"))
 
                 async def one(i, ask):
@@ -470,7 +511,8 @@ async def _drive(eng, cell: Cell, seed: int, seconds: float, trace_dir,
                 tasks += [asyncio.ensure_future(one(i, a))
                           for i, a in enumerate(asks)]
             elif loop_kind == "closed":
-                sessions = traffic_mod.closed_sessions(mix, seed, vocab)
+                sessions = traffic_mod.closed_sessions(mix, seed,
+                                                       eng.cfg.vocab)
                 queue = list(sessions)
 
                 async def client():
@@ -583,11 +625,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     compile_log = CompileLog()
     dev = devices[0]
     config, mix = cell.config, cell.mix
-    arch, form = config["architecture"], config["form"]
+    family = family_of(config, cell.bench)
+    arch, form = family.architecture(config), config["form"]
 
     phases = {"imports": time.perf_counter() - t_start}
     t = time.perf_counter()
-    eng = build_engine(config, seed)
+    eng = build_engine(config, seed, cell.bench)
     eng._ensure_state()
     phases["engine"] = time.perf_counter() - t
     widths, ts = step_shapes(eng, traffic_mod.longest(mix))
@@ -604,8 +647,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         eng.tracer = tracer
         trace_dir = (out_dir or ROOT / ".bench_trace") / f"trace-{seed}"
     records, timing = asyncio.run(_drive(
-        eng, cell, seed, seconds, trace_dir, t_start, arch["vocab_size"],
-        compile_log))
+        eng, cell, seed, seconds, trace_dir, t_start, compile_log))
     stats = {k: timing["stats1"][k] - timing["stats0"][k]
              for k in timing["stats1"]}
     import jax
@@ -618,7 +660,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     run = Run(cell, seed, seconds, records, timing["window"],
-              timing["setup_s"], stats, arch, form, dict(config["serve"]),
+              timing["setup_s"], stats, arch, form, family,
+              dict(config["serve"]),
               kv_itemsize, tracer=tracer, end=timing["end"])
     run.notes.update(setup_phases_s=phases, programs_warmed=n_programs,
                      compiles_in_window=timing["compiles"],
@@ -643,7 +686,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if checked:
         from . import reference
         res = reference.compare(
-            seed, arch, form, [(r.prompt, r.tokens) for r in checked],
+            seed, family, arch, form, [(r.prompt, r.tokens) for r in checked],
             pad_len=traffic_mod.longest(mix),
             pad_out=int(_out_max(mix)), control=control)
         gap, control_gap = res["gap"], res.get("control_gap")

@@ -12,14 +12,13 @@ from bench.harness import CHUNK_PROGRAM
 KERNEL = r"^paged_prefill_chunk_kernel\b"   # the kernel function's name
 
 
-def step_work(shape, arch, itemsize):
+def step_work(shape, family, arch, itemsize):
     """(ops, bytes) the chunk kernel needs in one step, all layers."""
-    per_tok = work.kv_bytes_per_token(arch, itemsize)
-    qo = (arch["num_attention_heads"] * arch["head_dim"]
-          * (2 + itemsize) * arch["num_hidden_layers"])
+    per_tok = family.kv_bytes_per_token(arch, itemsize)
+    qo = family.attention_io_bytes(arch, itemsize)
     ops = nbytes = 0
     for start, n in work.rows(shape):
-        ops += sum(work.attention_ops(arch, start + j + 1) for j in range(n))
+        ops += sum(family.attention_ops(arch, start + j + 1) for j in range(n))
         nbytes += (start + n) * per_tok + n * qo
     return ops, nbytes
 
@@ -30,7 +29,7 @@ def read(run):
         k = tr.named(inner, KERNEL)
         if not k:
             continue
-        o, b = step_work(shape, run.arch, run.kv_itemsize)
+        o, b = step_work(shape, run.family, run.arch, run.kv_itemsize)
         ops, nbytes, t = ops + o, nbytes + b, t + sum(e.dur for e in k)
     if not t:
         return None

@@ -46,7 +46,7 @@ def main() -> int:
     harness.check_chip(cell.chips)
     harness.enable_compile_cache(ROOT)
     log = harness.CompileLog()
-    eng = harness.build_engine(cell.config, args.seed)
+    eng = harness.build_engine(cell.config, args.seed, cell.bench)
     eng._ensure_state()
     widths, ts = harness.step_shapes(eng, traffic.longest(cell.mix))
     harness.precompile(eng, widths, ts, 8)
@@ -55,8 +55,7 @@ def main() -> int:
     for rate in args.rates:
         c = dataclasses.replace(cell, cell=dict(cell.cell, rate_rps=rate))
         recs, timing = asyncio.run(harness._drive(
-            eng, c, args.seed, args.seconds, None, time.perf_counter(),
-            cell.config["architecture"]["vocab_size"], log))
+            eng, c, args.seed, args.seconds, None, time.perf_counter(), log))
         w0, w1 = timing["window"]
         due = [r for r in recs if w0 <= r.due < w1]
         third = (w1 - w0) / 3

@@ -55,16 +55,26 @@ def test_broken_decode_step_is_not_correct(monkeypatch, fault):
     assert not out["correct"], out["checks"]
 
 
+@pytest.mark.parametrize("fault", [_token_altered, _state_unchanged])
+def test_broken_decode_step_of_the_teacher_is_not_correct(monkeypatch, fault):
+    """The same faults under the bf16 teacher's configuration."""
+    from repro.serving.engine import Engine
+    monkeypatch.setattr(Engine, "_decode_impl", fault(Engine._decode_impl))
+    out = _run("tiny-bf16.chat")
+    assert not out["correct"], out["checks"]
+
+
 @pytest.mark.parametrize("workload", ["tiny-packed.chat", "tiny-bf16.chat"])
 def test_lower_precision_control_is_not_correct(workload):
     """Teacher-forced on greedy continuations, the reference in the
     precision one step below the configuration's picks tokens that lie
     further below the reference's best than the cell's limit allows."""
     cell = tiny_cell(workload)
-    arch, form = cell.config["architecture"], cell.config["form"]
+    family = harness.family_of(cell.config)
+    arch, form = family.architecture(cell.config), cell.config["form"]
     seqs = _sequences(cell)
-    res = reference.compare(SEED, arch, form, seqs, pad_len=64, pad_out=16,
-                            control=True)
+    res = reference.compare(SEED, family, arch, form, seqs, pad_len=64,
+                            pad_out=16, control=True)
     limit = cell.cell["limits"]["logit_gap"]
     assert res["gap"] <= limit < res["control_gap"], res
 
@@ -104,12 +114,14 @@ def _sequences(cell):
     from bench import traffic
     asks = traffic.open_loop(cell.mix, 4.0, (10.0,), SEED, 512)[:6]
     seqs = []
-    arch, form = cell.config["architecture"], cell.config["form"]
+    family = harness.family_of(cell.config)
+    arch, form = family.architecture(cell.config), cell.config["form"]
     for a in asks:
         toks = list(a.prompt)
         for _ in range(8):
             h, table = reference.forward_hidden(
-                SEED, arch, form, jnp.asarray([toks + [0] * (64 - len(toks))]),
+                SEED, family, arch, form,
+                jnp.asarray([toks + [0] * (64 - len(toks))]),
                 jnp.asarray([[len(toks) - 1]]))
             logits = h[0, 0] @ table.astype(jnp.float32).T
             toks.append(int(jnp.argmax(logits)))

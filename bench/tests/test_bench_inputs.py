@@ -18,15 +18,17 @@ def test_program_weights_are_the_reference_weights(form):
     from bench import harness
 
     config = tiny_cell(f"tiny-{form}.chat").config
-    arch = config["architecture"]
+    qwen2 = harness.family_of(config)
+    arch = qwen2.architecture(config)
     shapes = jax.eval_shape(build_model(harness.model_config(config)).init,
                             jax.random.PRNGKey(0))
     seed = 2 ** 33 + 5
-    params = weights.program_params(seed, arch, form, shapes, pack_ternary)
+    params = weights.program_params(seed, qwen2, arch, form, shapes,
+                                    pack_ternary)
     key = weights.base_key(seed)
     stack = params["stack"]["pos0"]
     for layer in range(arch["num_hidden_layers"]):
-        ref = weights.layer_tensors(key, jnp.int32(layer), arch, form)
+        ref = qwen2.layer_tensors(key, jnp.int32(layer), arch, form)
         for mod, name in (("attn", "wq"), ("attn", "wo"), ("mlp", "down")):
             p = stack[mod][name]
             got = (unpack_ternary(p["w_packed"][layer], ref[name].shape[0])
@@ -36,7 +38,7 @@ def test_program_weights_are_the_reference_weights(form):
                                       np.asarray(ref["bk"]))
         np.testing.assert_array_equal(np.asarray(stack["norm2"]["scale"][layer]),
                                       np.asarray(ref["norm2"]))
-    top = weights.top_tensors(key, arch)
+    top = qwen2.top_tensors(key, arch, form)
     np.testing.assert_array_equal(np.asarray(params["embed"]["table"]),
                                   np.asarray(top["embed"]))
 

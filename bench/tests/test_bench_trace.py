@@ -66,7 +66,8 @@ def recorded():
     trace.anchor_host = host["anchor_host"]
     cell = tiny_cell("tiny-packed.chat")
     run = harness.Run(cell, 1234, 3.0, [], tuple(host["trace_window"]), 0.0,
-                      {}, host["arch"], host["form"], {},
+                      {}, host["arch"], host["form"],
+                      harness.family_of(cell.config), {},
                       host["kv_itemsize"],
                       peaks=json.loads((harness.BENCH / "peaks.json")
                                        .read_text())["devices"]["TPU v5 lite"],

@@ -1,36 +1,12 @@
-"""Operations and bytes the served model needs, from its shapes: the
-arithmetic the step and kernel metrics share.  Counts are of the work the
-algorithm needs for the rows that carry a request, never of the padding or
-idle rows the program also runs."""
+"""The arithmetic the step and kernel metrics share.  The operations and
+bytes of one position come from the configuration's architecture module
+(``linear_params``, ``attention_ops``, ``head_ops``, ``kv_bytes_per_token``,
+``attention_io_bytes``); counts are of the work the algorithm needs for the
+rows that carry a request, never of the padding or idle rows the program
+also runs."""
 from __future__ import annotations
 
 from typing import Dict
-
-
-def linear_params(arch: Dict) -> int:
-    """Weights of every linear layer of the stack (not the head)."""
-    d, f = arch["hidden_size"], arch["intermediate_size"]
-    q = arch["num_attention_heads"] * arch["head_dim"]
-    kv = arch["num_key_value_heads"] * arch["head_dim"]
-    return arch["num_hidden_layers"] * (2 * d * q + 2 * d * kv + 3 * d * f)
-
-
-def attention_ops(arch: Dict, context: int) -> int:
-    """QK and PV operations of one query position over ``context`` keys, all
-    layers."""
-    return (4 * arch["num_attention_heads"] * arch["head_dim"] * context
-            * arch["num_hidden_layers"])
-
-
-def head_ops(arch: Dict) -> int:
-    """Tied head of one position."""
-    return 2 * arch["hidden_size"] * arch["vocab_size"]
-
-
-def kv_bytes_per_token(arch: Dict, itemsize: int) -> int:
-    """K and V of one position, all layers, in the pool's dtype."""
-    return (2 * arch["num_key_value_heads"] * arch["head_dim"] * itemsize
-            * arch["num_hidden_layers"])
 
 
 def least_seconds(linear_ops: float, other_ops: float, form: str,
